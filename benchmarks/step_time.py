@@ -161,12 +161,12 @@ def _overlap_worker(quick: bool) -> dict:
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
     from repro.core.buckets import (
         MAX_INFLIGHT_BUCKETS, BucketLayout, build_bucket_step,
         xi_from_folded_sq,
     )
     from repro.core.dsgd import make_topology
+    from repro.launch.mesh import make_mesh
     from repro.optim.sgd import sgd
 
     n = 8
@@ -174,7 +174,7 @@ def _overlap_worker(quick: bool) -> dict:
     reps = 8 if quick else 16
     mbs = (0.25, DEFAULT_BUCKET_MB) if quick else (0.5, DEFAULT_BUCKET_MB, 2.0)
 
-    mesh = compat.make_mesh((n,), ("gossip",))
+    mesh = make_mesh((n,), ("gossip",))
     lead2 = NamedSharding(mesh, P("gossip", None))
     rep_s = NamedSharding(mesh, P())
     gvec = NamedSharding(mesh, P("gossip"))

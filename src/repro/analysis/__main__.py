@@ -102,7 +102,6 @@ def run_collectives():
     import numpy as np
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
     from repro.analysis.collectives import (
         assert_signatures_consistent,
         collective_signature,
@@ -111,8 +110,9 @@ def run_collectives():
     )
     from repro.analysis.report import run_pass
     from repro.core.dsgd import make_topology
+    from repro.launch.mesh import make_mesh
 
-    mesh = compat.make_mesh((N,), ("gossip",))
+    mesh = make_mesh((N,), ("gossip",))
     x = np.arange(N * 4, dtype=np.float32).reshape(N, 4)
     alive = np.ones((N,), np.float32)
 
@@ -126,13 +126,13 @@ def run_collectives():
             seen.add(prog.cache_key)
 
             def thunk(prog=prog):
-                jb = jax.jit(compat.shard_map(
+                jb = jax.jit(jax.shard_map(
                     lambda v: prog.apply_shard(v, "gossip"),
-                    mesh=mesh, in_specs=P("gossip"), out_specs=P("gossip"),
+                    mesh=mesh, check_vma=False, in_specs=P("gossip"), out_specs=P("gossip"),
                 ))
-                jm = jax.jit(compat.shard_map(
+                jm = jax.jit(jax.shard_map(
                     lambda v, a: prog.apply_shard_masked(v, "gossip", a),
-                    mesh=mesh, in_specs=(P("gossip"), P()),
+                    mesh=mesh, check_vma=False, in_specs=(P("gossip"), P()),
                     out_specs=P("gossip"),
                 ))
                 if prog.permute_tables() is not None:

@@ -11,9 +11,8 @@ those with two reusable primitives:
 ``assert_no_retrace`` / ``watch_retrace``
     A context manager hooking jax's monitoring events
     (``jaxpr_trace_duration`` / ``backend_compile_duration`` — the
-    counters ``jax.jit`` emits on every trace and XLA compile).  One
-    module-level listener is registered lazily and feeds a stack of
-    active frames, because jax 0.4.37 has no public unregister.  Works
+    counters ``jax.jit`` emits on every trace and XLA compile).  Each
+    frame registers its own listener and unregisters it on exit.  Works
     for ANY jit — including the engines' internal executables that never
     appear under a program key.
 
@@ -51,11 +50,6 @@ except ImportError:  # pragma: no cover - jax moved the constants
     JAXPR_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
     BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
-# jax 0.4.37 has no public listener unregister, so exactly one listener is
-# registered for the process lifetime; frames opt in/out via this stack.
-_frames: list["RetraceStats"] = []
-_registered = False
-
 
 @dataclasses.dataclass
 class RetraceStats:
@@ -70,37 +64,24 @@ class RetraceStats:
         return self.traces == 0 and self.compiles == 0
 
 
-def _listener(event, duration, **kwargs):
-    if not _frames:
-        return
-    if event == JAXPR_TRACE_EVENT:
-        for f in _frames:
-            f.traces += 1
-    elif event == BACKEND_COMPILE_EVENT:
-        for f in _frames:
-            f.compiles += 1
-
-
-def _ensure_listener() -> None:
-    global _registered
-    if _registered:
-        return
-    import jax.monitoring
-
-    jax.monitoring.register_event_duration_secs_listener(_listener)
-    _registered = True
-
-
 @contextlib.contextmanager
 def watch_retrace(label: str = ""):
     """Count jit traces / XLA compiles inside the ``with`` body."""
-    _ensure_listener()
+    import jax.monitoring
+
     stats = RetraceStats(label)
-    _frames.append(stats)
+
+    def listener(event, duration, **kwargs):
+        if event == JAXPR_TRACE_EVENT:
+            stats.traces += 1
+        elif event == BACKEND_COMPILE_EVENT:
+            stats.compiles += 1
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
     try:
         yield stats
     finally:
-        _frames.remove(stats)
+        jax.monitoring.unregister_event_duration_listener(listener)
 
 
 @contextlib.contextmanager

@@ -135,7 +135,7 @@ def input_specs(
             )
         b = shape.global_batch // n_nodes
         s = shape.seq_len
-        lead = (n_nodes, b) if n_nodes > 1 else (b,)
+        lead = (n_nodes, b)
         specs = {
             "tokens": jax.ShapeDtypeStruct(lead + (s,), i32),
             "targets": jax.ShapeDtypeStruct(lead + (s,), i32),

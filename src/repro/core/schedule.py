@@ -66,11 +66,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:  # jax-version shim (PR 1); degrade gracefully when absent
-    from repro import compat as _compat
-except ImportError:  # pragma: no cover
-    _compat = None
-
 PyTree = Any
 
 __all__ = [
@@ -203,12 +198,7 @@ def _flat_axis_index(axis_names):
         return jax.lax.axis_index(axis_names)
     idx = jnp.zeros((), jnp.int32)
     for a in axis_names:
-        size = (
-            _compat.axis_size(a)
-            if _compat is not None
-            else jax.lax.psum(jnp.ones((), jnp.int32), a)
-        )
-        idx = idx * size + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
 
 

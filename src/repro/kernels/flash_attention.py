@@ -13,7 +13,8 @@ Layouts:
   out:  (B, H, Sq, D)
 
 Validated against ``ref.flash_attention_ref`` in interpret mode on CPU
-(tests/test_kernels.py); on real TPUs pass ``interpret=False``.
+(tests/test_kernels.py).  ``interpret=None`` (the default) compiles the
+kernel on a TPU backend and interprets it everywhere else.
 """
 from __future__ import annotations
 
@@ -96,9 +97,11 @@ def flash_attention(
     window: int | None = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """q: (B, H, Sq, D); k/v: (B, KV, Sk, D) with H % KV == 0 -> (B, H, Sq, D)."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     b, h, sq, d = q.shape
     kv, sk = k.shape[1], k.shape[2]
     group = h // kv

@@ -14,14 +14,16 @@ costs ``(deg + 5)`` HBM reads + 3 writes of P; this kernel fuses it into
 ``theta' = w_0·theta + Σ_i w_i·n_i − lr·m'``, which needs no pre-send
 materialization of theta*).
 
-Two granularities share one kernel body:
+Two granularities share one kernel math (``_mix_block``):
 
-  * ``gossip_update``          — one node: theta (P,), neighbors (deg, P),
-    weights (deg+1,) in SMEM.  The original single-replica entry point.
+  * ``gossip_update``          — one node's parameter leaf, any shape: deg
+    leaf-shaped neighbor buffers, weights (deg+1,) in SMEM.  The
+    production-path round (``fused_apply_shard``) runs it per leaf.
   * ``gossip_program_update``  — a whole stacked replica axis: theta
-    (n, P), neighbors (n, deg, P), per-node weights (n, deg+1); the grid
-    runs (node, block) and each node's (deg+1,) weight row is selected
-    into SMEM by the BlockSpec index map.  This is the executor for
+    (n, P), deg (n, P) neighbor buffers, per-node weights (n, deg+1); the
+    grid runs (node group, block) and each cell mixes its nodes' rows with
+    their (deg+1,) weight rows, read as one column per neighbor slot and
+    broadcast across the lanes.  This is the executor for
     compiled PPermute programs (circulant offsets, matchings, and
     edge-colored irregular graphs alike) — ``fused_apply_stacked`` feeds
     it straight from a ``GossipProgram``.
@@ -40,13 +42,26 @@ idles until its activation flips the row live, and a *deadline-benched*
 straggler keeps ``update = 1`` with edges masked — it descends locally
 while sitting out the gossip round.
 
-Layout: parameters are flattened and blocked 1-D ((block,) VMEM tiles,
-8·128-aligned); neighbor buffers arrive stacked (deg, P) — on TPU these are
-the ppermute landing buffers, so no extra copy.  Weights live in SMEM.
+Layout: ``gossip_update`` tiles a leaf's 2-D view in (8k, 128j) VMEM tiles
+of at most max(block, 8 x 1024) elements; a leaf whose rows are a multiple
+of 8 and whose last dim is a multiple of 128 is viewed in place, any other
+is flattened and zero-padded to whole (8, 1024) tiles.  Its neighbor
+buffers are separate operands — on TPU the ppermute landing buffers, so no
+stack copy — and its weights and fault row live in SMEM.  Both kernels
+take their neighbors as a sequence of deg landing buffers.
+``gossip_program_update`` tiles the (n, P) matrices in place
+as (rows, block) with rows = n, or 16 when 16 divides n: the TPU's tiling
+rule takes a whole dimension or a multiple of 16 rows (8 for f32), and
+refuses the (1, block) tile of one node.  Its weight and fault tables ride
+in VMEM as (rows, deg+1) tiles.  Viewing (n, P) as (n, P/128, 128) instead
+would make XLA relayout every operand, and the TPU compiler's bf16 relayout
+of a stacked (n, deg, P) neighbor array takes time that grows with P
+(minutes at one 4096 x 14336 matrix).
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -121,11 +136,27 @@ def _mix_block(w, f, theta, nbrs, grad, mom, lr, beta, *, deg, mix_order,
     return acc.astype(out_dtype), m_new
 
 
-def _kernel(sc_ref, w_ref, f_ref, theta_ref, nbr_ref, grad_ref, mom_ref,
-            out_ref, mom_out_ref, *, deg: int, mix_order: str):
+def _program_kernel(sc_ref, w_ref, f_ref, theta_ref, *refs, deg: int,
+                    mix_order: str):
+    nbr_refs, (grad_ref, mom_ref, out_ref, mom_out_ref) = refs[:deg], refs[deg:]
+    # this tile's (rows, deg+1) weight and fault rows: column k is one
+    # value per node, broadcast across the node's lanes
+    out, m_new = _mix_block(
+        lambda k: w_ref[:, k:k + 1], lambda k: f_ref[:, k:k + 1],
+        theta_ref[...], lambda i: nbr_refs[i][...], grad_ref[...],
+        mom_ref[...], sc_ref[0], sc_ref[1],
+        deg=deg, mix_order=mix_order, out_dtype=out_ref.dtype,
+    )
+    out_ref[...] = out
+    mom_out_ref[...] = m_new
+
+
+def _leaf_kernel(sc_ref, w_ref, f_ref, theta_ref, *refs, deg: int,
+                 mix_order: str):
+    nbr_refs, (grad_ref, mom_ref, out_ref, mom_out_ref) = refs[:deg], refs[deg:]
     out, m_new = _mix_block(
         lambda k: w_ref[k], lambda k: f_ref[k], theta_ref[...],
-        lambda i: nbr_ref[i], grad_ref[...], mom_ref[...],
+        lambda i: nbr_refs[i][...], grad_ref[...], mom_ref[...],
         sc_ref[0], sc_ref[1],
         deg=deg, mix_order=mix_order, out_dtype=out_ref.dtype,
     )
@@ -133,60 +164,86 @@ def _kernel(sc_ref, w_ref, f_ref, theta_ref, nbr_ref, grad_ref, mom_ref,
     mom_out_ref[...] = m_new
 
 
-def _program_kernel(sc_ref, w_ref, f_ref, theta_ref, nbr_ref, grad_ref,
-                    mom_ref, out_ref, mom_out_ref, *, deg: int, mix_order: str):
-    out, m_new = _mix_block(
-        lambda k: w_ref[0, k], lambda k: f_ref[0, k], theta_ref[0],
-        lambda i: nbr_ref[0, i], grad_ref[0], mom_ref[0],
-        sc_ref[0], sc_ref[1],
-        deg=deg, mix_order=mix_order, out_dtype=out_ref.dtype,
-    )
-    out_ref[0] = out
-    mom_out_ref[0] = m_new
+_LANES = 1024  # lane width of the padded 2-D view of a leaf that cannot tile
+
+
+def _leaf_view(shape) -> tuple[int, int, int]:
+    """(rows, cols, pad) of the 2-D view the kernel tiles a leaf in.
+
+    A leaf of 2+ dims whose row count is a multiple of 8 and whose last dim
+    is a multiple of 128 is viewed (rows, last dim): merging leading dims
+    keeps the TPU's tiled layout, so the view costs no copy.  Any other
+    leaf (1-D, an odd row count, an odd vocab) is flattened and
+    zero-padded by ``pad`` elements to whole (8, _LANES) tiles."""
+    size = math.prod(shape)
+    cols = shape[-1] if shape else 1
+    rows = size // cols
+    if len(shape) >= 2 and rows % 8 == 0 and cols % 128 == 0:
+        return rows, cols, 0
+    pad = -size % (8 * _LANES)
+    return (size + pad) // _LANES, _LANES, pad
+
+
+def _leaf_tile(rows: int, cols: int, block: int) -> tuple[int, int]:
+    """(8k, 128j) tile of a (rows, cols) leaf view: at most 1024 lanes,
+    and the most rows (a multiple of 8 dividing ``rows``) that keep the
+    tile within ``block`` elements, or 8 rows when one 8-row tile is
+    already larger.  So a tile never exceeds max(block, 8 x 1024)."""
+    bc = math.gcd(cols, 1024)
+    q = rows // 8
+    d = max(1, min(q, block // (8 * bc)))
+    while q % d:
+        d -= 1
+    return 8 * d, bc
 
 
 @functools.partial(
     jax.jit, static_argnames=("block", "interpret", "mix_order")
 )
-def _gossip_update(theta, neighbors, weights, fault, grad, momentum, scalars,
-                   *, block: int, interpret: bool, mix_order: str):
-    (p,) = theta.shape
-    deg = neighbors.shape[0]
-    block = min(block, p)
-    if p % block:
-        raise ValueError(f"param length {p} must tile by block {block}")
-    grid = (p // block,)
-    return pl.pallas_call(
-        functools.partial(_kernel, deg=deg, mix_order=mix_order),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),           # [lr, beta]
-            pl.BlockSpec(memory_space=pltpu.SMEM),           # weights
-            pl.BlockSpec(memory_space=pltpu.SMEM),           # fault row
-            pl.BlockSpec((block,), lambda i: (i,)),          # theta
-            pl.BlockSpec((deg, block), lambda i: (0, i)),    # neighbors
-            pl.BlockSpec((block,), lambda i: (i,)),          # grad
-            pl.BlockSpec((block,), lambda i: (i,)),          # momentum
-        ],
-        out_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-        ],
+def _leaf_update(theta, neighbors, weights, fault, grad, momentum, scalars,
+                 *, block: int, interpret: bool, mix_order: str):
+    """One parameter leaf in its own shape, over ``neighbors``, a tuple of
+    deg leaf-shaped landing buffers.  The leaf goes through its
+    ``_leaf_view``; only a leaf that cannot tile is padded, and the
+    outputs overwrite theta and momentum."""
+    shape = theta.shape
+    rows, cols, pad = _leaf_view(shape)
+    br, bc = _leaf_tile(rows, cols, block)
+    _check_budget(len(neighbors), br * bc, interpret)
+
+    def view(x):
+        if pad:
+            x = jnp.pad(x.reshape(-1), (0, pad))
+        return x.reshape(rows, cols)
+
+    def unview(x):
+        return (x.reshape(-1)[:theta.size] if pad else x).reshape(shape)
+
+    tile = pl.BlockSpec((br, bc), lambda i, j: (i, j))
+    out, m_new = pl.pallas_call(
+        functools.partial(_leaf_kernel, deg=len(neighbors), mix_order=mix_order),
+        grid=(rows // br, cols // bc),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] * 3
+        + [tile] * (len(neighbors) + 3),
+        out_specs=[tile, tile],
         out_shape=[
-            jax.ShapeDtypeStruct((p,), theta.dtype),
-            jax.ShapeDtypeStruct((p,), jnp.float32),
+            jax.ShapeDtypeStruct((rows, cols), theta.dtype),
+            jax.ShapeDtypeStruct((rows, cols), jnp.float32),
         ],
+        # in place: theta -> theta', momentum -> m' (the tiles are disjoint)
+        input_output_aliases={3: 0, 5 + len(neighbors): 1},
         interpret=interpret,
     )(scalars, weights.astype(jnp.float32), fault.astype(jnp.float32),
-      theta, neighbors, grad, momentum)
+      view(theta), *map(view, neighbors), view(grad), view(momentum))
+    return unview(out), unview(m_new)
 
 
 def gossip_update(
-    theta: jax.Array,      # (P,)
-    neighbors: jax.Array,  # (deg, P)
+    theta: jax.Array,      # one leaf, any shape
+    neighbors,             # deg landing buffers shaped like theta
     weights: jax.Array,    # (deg + 1,) [self, n_1..n_deg]
-    grad: jax.Array,       # (P,)
-    momentum: jax.Array,   # (P,) float32
+    grad: jax.Array,       # like theta
+    momentum: jax.Array,   # like theta, float32
     *,
     lr,
     beta,
@@ -195,19 +252,30 @@ def gossip_update(
     interpret: bool | None = None,
     mix_order: str = "post",
 ) -> tuple[jax.Array, jax.Array]:
-    """Returns (theta', m').  lr/beta/weights/fault are runtime values — LR
-    schedules, degraded weight rows, and fault masks never recompile."""
+    """Returns (theta', m') for one node's leaf.  ``neighbors`` is any
+    sequence of deg buffers, so a (deg, *theta.shape) array unstacks into
+    one; a tuple of ppermute results is used as it is.  lr/beta/weights/
+    fault are runtime values — LR schedules, degraded weight rows, and
+    fault masks never recompile."""
     interpret = _auto_interpret(interpret)
+    neighbors = tuple(neighbors)
     scalars = jnp.stack(
         [jnp.asarray(lr, jnp.float32), jnp.asarray(beta, jnp.float32)]
     )
     if fault is None:
-        fault = jnp.ones((neighbors.shape[0] + 1,), jnp.float32)
-    return _gossip_update(
+        fault = jnp.ones((len(neighbors) + 1,), jnp.float32)
+    return _leaf_update(
         theta, neighbors, weights, fault, grad, momentum, scalars,
         block=_auto_block(block, interpret), interpret=interpret,
         mix_order=mix_order,
     )
+
+
+def _node_rows(n: int) -> int:
+    """Nodes per tile: all n, or 16 when n is a multiple of 16 (the TPU's
+    tiling rule takes a full dimension, or a multiple of 16 rows for bf16
+    and of 8 for f32)."""
+    return 16 if n % 16 == 0 else n
 
 
 @functools.partial(
@@ -216,44 +284,41 @@ def gossip_update(
 def _gossip_program_update(theta, neighbors, weights, fault, grad, momentum,
                            scalars, *, block: int, interpret: bool,
                            mix_order: str):
+    """``neighbors`` is a tuple of deg (n, P) landing buffers."""
     n, p = theta.shape
-    deg = neighbors.shape[1]
+    deg = len(neighbors)
     block = min(block, p)
     if p % block:
         raise ValueError(f"param length {p} must tile by block {block}")
-    grid = (n, p // block)
+    rows = _node_rows(n)
+    _check_budget(deg, rows * block, interpret)
+    tile = pl.BlockSpec((rows, block), lambda i, j: (i, j))
+    table = pl.BlockSpec((rows, deg + 1), lambda i, j: (i, 0))
     return pl.pallas_call(
         functools.partial(_program_kernel, deg=deg, mix_order=mix_order),
-        grid=grid,
+        grid=(n // rows, p // block),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),              # [lr, beta]
-            # this node's (deg+1,) weight row, selected into SMEM per node
-            pl.BlockSpec((1, deg + 1), lambda i, j: (i, 0),
-                         memory_space=pltpu.SMEM),
-            # this node's (deg+1,) fault row [update, edge_1..edge_deg]
-            pl.BlockSpec((1, deg + 1), lambda i, j: (i, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, block), lambda i, j: (i, j)),       # theta
-            pl.BlockSpec((1, deg, block), lambda i, j: (i, 0, j)),  # nbrs
-            pl.BlockSpec((1, block), lambda i, j: (i, j)),       # grad
-            pl.BlockSpec((1, block), lambda i, j: (i, j)),       # momentum
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # [lr, beta]
+            table,                                  # weights (n, deg+1)
+            table,                                  # fault rows, same layout
+            tile,                                   # theta
+            *[tile] * deg,                          # neighbors
+            tile,                                   # grad
+            tile,                                   # momentum
         ],
-        out_specs=[
-            pl.BlockSpec((1, block), lambda i, j: (i, j)),
-            pl.BlockSpec((1, block), lambda i, j: (i, j)),
-        ],
+        out_specs=[tile, tile],
         out_shape=[
             jax.ShapeDtypeStruct((n, p), theta.dtype),
             jax.ShapeDtypeStruct((n, p), jnp.float32),
         ],
         interpret=interpret,
     )(scalars, weights.astype(jnp.float32), fault.astype(jnp.float32),
-      theta, neighbors, grad, momentum)
+      theta, *neighbors, grad, momentum)
 
 
 def gossip_program_update(
     theta: jax.Array,      # (n, P) stacked replicas
-    neighbors: jax.Array,  # (n, deg, P) permute landing buffers
+    neighbors,             # deg (n, P) landing buffers
     weights: jax.Array,    # (n, deg + 1) per-node [self, w_1..w_deg]
     grad: jax.Array,       # (n, P)
     momentum: jax.Array,   # (n, P) float32
@@ -275,10 +340,9 @@ def gossip_program_update(
     scalars = jnp.stack(
         [jnp.asarray(lr, jnp.float32), jnp.asarray(beta, jnp.float32)]
     )
+    neighbors = tuple(neighbors)
     if fault is None:
-        fault = jnp.ones(
-            (theta.shape[0], neighbors.shape[1] + 1), jnp.float32
-        )
+        fault = jnp.ones((theta.shape[0], len(neighbors) + 1), jnp.float32)
     return _gossip_program_update(
         theta, neighbors, weights, fault, grad, momentum, scalars,
         block=_auto_block(block, interpret), interpret=interpret,
@@ -304,6 +368,15 @@ def _unflatten_stacked(mat, tree, sizes):
         out.append(mat[:, off:off + size].reshape(leaf.shape).astype(leaf.dtype))
         off += size
     return jax.tree.unflatten(jax.tree.structure(tree), out)
+
+
+def _landing_buffers(wire, srcs):
+    """deg (n, P) neighbor buffers: row i of slot k is node srcs[i, k]'s
+    wire."""
+    return tuple(
+        jnp.concatenate([wire[int(src):int(src) + 1] for src in srcs[:, k]])
+        for k in range(srcs.shape[1])
+    )
 
 
 def _fault_rows_stacked(fault, srcs, n):
@@ -392,8 +465,7 @@ def fused_apply_stacked(
         wire = (theta.astype(jnp.float32) - lr32 * m_wire).astype(theta.dtype)
     else:
         wire = theta
-    # (n, deg) fancy index along the node axis -> (n, deg, P) landing buffers
-    nbrs = jnp.take(wire, jnp.asarray(srcs), axis=0)
+    nbrs = _landing_buffers(wire, srcs)
 
     out, m_new = gossip_program_update(
         theta, nbrs, jnp.asarray(weights), g_mat, m_mat,
@@ -467,7 +539,7 @@ def fused_bucket_update(
         wire = (theta.astype(jnp.float32) - lr32 * m_wire).astype(theta.dtype)
     else:
         wire = theta
-    nbrs = jnp.take(wire, jnp.asarray(srcs), axis=0)
+    nbrs = _landing_buffers(wire, srcs)
 
     out, m_new = gossip_program_update(
         theta, nbrs, jnp.asarray(weights), g_mat, m_mat,
@@ -478,21 +550,6 @@ def fused_bucket_update(
         out = out[:, :p]
         m_new = m_new[:, :p]
     return out, m_new
-
-
-def _flatten_local(tree):
-    leaves = jax.tree.leaves(tree)
-    flat = [x.reshape(-1) for x in leaves]
-    return jnp.concatenate(flat), [f.shape[0] for f in flat]
-
-
-def _unflatten_local(vec, tree, sizes):
-    leaves = jax.tree.leaves(tree)
-    out, off = [], 0
-    for leaf, size in zip(leaves, sizes):
-        out.append(vec[off:off + size].reshape(leaf.shape).astype(leaf.dtype))
-        off += size
-    return jax.tree.unflatten(jax.tree.structure(tree), out)
 
 
 def fused_apply_shard(
@@ -512,9 +569,12 @@ def fused_apply_shard(
     """The production-path twin of ``fused_apply_stacked``: one fused
     momentum-SGD + gossip round on per-node values inside ``shard_map``.
 
-    One ``jax.lax.ppermute`` per compiled permute delivers the neighbor
-    landing buffers (non-participating nodes receive zeros, matching the
-    zero weight in their SMEM row); this node's (deg+1,) weight row is
+    Runs ``gossip_update`` leaf by leaf in each leaf's own shape, so the
+    round makes no flattened copy of the tree (only a leaf that cannot
+    tile, such as a norm gain, is padded on its own).  One
+    ``jax.lax.ppermute`` per compiled permute delivers each
+    leaf's landing buffers (non-participating nodes receive zeros, matching
+    the zero weight in their SMEM row); this node's (deg+1,) weight row is
     selected by its flat axis index.  ``fault`` carries the replicated
     runtime masks — this node slices its own update flag and edge-mask row,
     so every realization reuses the one executable.  Returns
@@ -531,22 +591,9 @@ def fused_apply_shard(
     srcs, weights = tables
     interpret = _auto_interpret(interpret)
     block = _auto_block(block, interpret)
-    theta, sizes = _flatten_local(params)
-    g_vec, _ = _flatten_local(grads)
-    if momentum == () or momentum is None:
-        m_vec = jnp.zeros(theta.shape, jnp.float32)
-        had_momentum = False
-    else:
-        m_vec, _ = _flatten_local(momentum)
-        had_momentum = True
-    p = theta.shape[0]
-    block = min(block, p)
-    _check_budget(srcs.shape[1], block, interpret)
-    pad = (-p) % block
-    if pad:
-        theta = jnp.pad(theta, (0, pad))
-        g_vec = jnp.pad(g_vec, (0, pad))
-        m_vec = jnp.pad(m_vec, (0, pad))
+    had_momentum = momentum is not None and not (
+        isinstance(momentum, tuple) and not momentum
+    )
 
     idx = _flat_axis_index(axis_names)
     lr32 = jnp.asarray(lr, jnp.float32)
@@ -555,26 +602,34 @@ def fused_apply_shard(
     if fault is not None:
         # this node's row of the shared edge-up mask formula
         frow = _fault_rows_stacked(fault, srcs, srcs.shape[0])[idx]
-    if mix_order == "post":
-        m_wire = beta32 * m_vec + g_vec.astype(jnp.float32)
-        if fault is not None:
-            m_wire = m_wire * frow[0]
-        wire = (theta.astype(jnp.float32) - lr32 * m_wire).astype(theta.dtype)
-    else:
-        wire = theta
-    nbrs = jnp.stack(
-        [jax.lax.ppermute(wire, axis_names, list(op.perm)) for op in program.ops]
-    )
     wrow = jnp.asarray(weights)[idx]
-    out, m_new = gossip_update(
-        theta, nbrs, wrow, g_vec, m_vec,
-        lr=lr32, beta=beta32, fault=frow, block=block, interpret=interpret,
-        mix_order=mix_order,
-    )
-    if pad:
-        out = out[:p]
-        m_new = m_new[:p]
-    new_params = _unflatten_local(out, params, sizes)
+
+    def leaf_round(x, g, m):
+        m32 = jnp.zeros(x.shape, jnp.float32) if m is None else m.astype(jnp.float32)
+        if mix_order == "post":
+            m_wire = beta32 * m32 + g.astype(jnp.float32)
+            if fault is not None:
+                m_wire = m_wire * frow[0]
+            wire = (x.astype(jnp.float32) - lr32 * m_wire).astype(x.dtype)
+        else:
+            wire = x
+        nbrs = tuple(
+            jax.lax.ppermute(wire, axis_names, list(op.perm))
+            for op in program.ops
+        )
+        return gossip_update(
+            x, nbrs, wrow, g, m32, lr=lr32, beta=beta32, fault=frow,
+            block=block, interpret=interpret, mix_order=mix_order,
+        )
+
+    leaves, treedef = jax.tree.flatten(params)
+    g_leaves = jax.tree.leaves(grads)
+    m_leaves = jax.tree.leaves(momentum) if had_momentum else [None] * len(leaves)
+    rounds = [leaf_round(*a) for a in zip(leaves, g_leaves, m_leaves)]
+    new_params = jax.tree.unflatten(treedef, [o for o, _ in rounds])
     if not had_momentum:
         return new_params, ()
-    return new_params, _unflatten_local(m_new, momentum, sizes)
+    return new_params, jax.tree.unflatten(
+        jax.tree.structure(momentum),
+        [mn.astype(m.dtype) for (_, mn), m in zip(rounds, m_leaves)],
+    )

@@ -38,8 +38,10 @@ def flash_attention(q, k, v, *, causal=True, window=None, block_q=128, block_k=1
 
 def gossip_update(theta, neighbors, weights, grad, momentum, *, lr, beta,
                   block=1024, interpret=None, mix_order="post"):
-    """lr/beta are runtime scalars (LR schedules do not retrigger compiles);
-    interpret=None auto-detects the backend inside the kernel module."""
+    """One node's leaf over deg leaf-shaped neighbor buffers (a sequence,
+    or one array stacked on a leading axis); lr/beta are runtime scalars
+    (LR schedules do not retrigger compiles); interpret=None auto-detects
+    the backend inside the kernel module."""
     return _gossip(
         theta, neighbors, weights, grad, momentum,
         lr=lr, beta=beta, block=block, interpret=interpret,
@@ -49,7 +51,8 @@ def gossip_update(theta, neighbors, weights, grad, momentum, *, lr, beta,
 
 def gossip_program_update(theta, neighbors, weights, grad, momentum, *, lr,
                           beta, block=1024, interpret=None, mix_order="post"):
-    """(n, P) stacked executor with per-node (deg+1,) SMEM weight rows."""
+    """(n, P) stacked executor with per-node (deg+1,) weight rows;
+    ``neighbors`` is a sequence of deg (n, P) landing buffers."""
     from repro.kernels.gossip_update import gossip_program_update as _prog
 
     return _prog(

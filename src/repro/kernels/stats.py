@@ -35,8 +35,12 @@ def _kernel(x_ref, o_ref, acc_ref, *, nblocks: int):
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def l2_norms(x: jax.Array, *, block: int = 2048, interpret: bool = True) -> jax.Array:
-    """Row L2 norms of (R, P) -> (R,) float32."""
+def l2_norms(x: jax.Array, *, block: int = 2048,
+             interpret: bool | None = None) -> jax.Array:
+    """Row L2 norms of (R, P) -> (R,) float32.  ``interpret=None`` compiles
+    the kernel on a TPU backend and interprets it everywhere else."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     r, p = x.shape
     block = min(block, p)
     if p % block:

@@ -153,9 +153,7 @@ def run_one(arch: str, shape_name: str, mesh_kind: str, topology: str, mixing: s
         "temp_bytes": int(getattr(mem, "temp_size_in_bytes", 0)),
         "alias_bytes": int(getattr(mem, "alias_size_in_bytes", 0)),
     }
-    from repro.compat import cost_analysis as _cost_analysis
-
-    cost = _cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     rec["cost"] = {
         "flops": float(cost.get("flops", 0.0)),
         "bytes_accessed": float(cost.get("bytes accessed", 0.0)),

@@ -15,11 +15,6 @@ from typing import Sequence
 
 import jax
 
-try:  # jax-version shim (PR 1); degrade gracefully to the modern API
-    from repro import compat as _compat
-except ImportError:  # pragma: no cover
-    _compat = None
-
 __all__ = [
     "make_production_mesh",
     "make_mesh",
@@ -29,8 +24,6 @@ __all__ = [
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]) -> jax.sharding.Mesh:
-    if _compat is not None:
-        return _compat.make_mesh(shape, axes)
     return jax.make_mesh(
         tuple(shape),
         tuple(axes),

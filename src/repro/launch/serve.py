@@ -25,11 +25,6 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-try:  # jax-version shim (PR 1); degrade gracefully to the modern API
-    from repro import compat as _compat
-except ImportError:  # pragma: no cover
-    _compat = None
-
 from repro.configs.base import ArchConfig, InputShape
 from repro.launch import sharding as shd
 from repro.models import transformer as tfm
@@ -137,7 +132,7 @@ class ServeEngine:
         if "patch_embeds" in batch:
             args += (batch["patch_embeds"],)
             in_shardings += (in_sh["patch_embeds"],)
-        with (_compat.set_mesh(self.mesh) if _compat is not None else jax.set_mesh(self.mesh)):
+        with jax.set_mesh(self.mesh):
             return jax.jit(fn, in_shardings=in_shardings).lower(
                 abstract_params(self.defs), *args
             )
@@ -181,7 +176,7 @@ class ServeEngine:
         tok_sh = NamedSharding(self.mesh, P(batch_ax, None))
         pos_sh = NamedSharding(self.mesh, P())
         fn = self.decode_fn(window)
-        with (_compat.set_mesh(self.mesh) if _compat is not None else jax.set_mesh(self.mesh)):
+        with jax.set_mesh(self.mesh):
             return jax.jit(
                 fn,
                 in_shardings=(self.param_shardings, tok_sh, pos_sh, state_sh),

@@ -126,12 +126,14 @@ def batch_sharding(
     *,
     stacked: bool,
 ) -> NamedSharding:
-    """Training batches: (G, b, ...) with G over gossip axes (stacked), or
-    (B, ...) with B over all non-model axes (G == 1)."""
+    """Training batches (G, b, ...): G over the gossip axes (stacked), or,
+    when G == 1, b over all non-model axes."""
     if stacked:
         return NamedSharding(mesh, P(gossip_axes, *([None] * (ndim - 1))))
     data_axes = tuple(a for a in mesh.axis_names if a != "model")
-    return NamedSharding(mesh, P(data_axes if data_axes else None, *([None] * (ndim - 1))))
+    return NamedSharding(
+        mesh, P(None, data_axes if data_axes else None, *([None] * (ndim - 2)))
+    )
 
 
 def tree_size_bytes(tree: PyTree) -> int:

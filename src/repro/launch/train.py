@@ -32,13 +32,6 @@ computes the consensus distance Ξ_t over the gossip-stacked global state
 topology's ``ConsensusController``; the measured ratio Ξ_t/Ξ_0 — not the
 epoch law — steps the schedule down its pre-enumerated ladder, so the
 bounded-executable-set invariant holds unchanged.
-
-jax-version note: partial-manual shard_map needs the modern manual-axes API
-(``repro/compat.py``).  On old jax (0.4.37 in this container) the trainer
-transparently switches to the *stacked* GSPMD realization — vmap over the
-gossip axis + the program's stacked interpreter, whose rolls XLA lowers to
-collective-permutes on the sharded axis — numerically identical and proven
-against the simulator oracle.
 """
 from __future__ import annotations
 
@@ -52,11 +45,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
-
-try:  # jax-version shim (PR 1); degrade gracefully to modern-API-only
-    from repro import compat as _compat
-except ImportError:  # pragma: no cover
-    _compat = None
 
 from repro.checkpoint.ckpt import validate_run_config as _validate_run_config
 from repro.core import dbench
@@ -72,31 +60,7 @@ from repro.optim.sgd import Optimizer
 
 PyTree = Any
 
-__all__ = ["SPMDTrainer", "TrainState"]
-
-
-def _set_mesh(mesh):
-    if _compat is not None:
-        return _compat.set_mesh(mesh)
-    return jax.set_mesh(mesh)
-
-
-def _has_manual_axes() -> bool:
-    if _compat is not None:
-        return _compat.HAS_MANUAL_AXES_API
-    return hasattr(jax, "shard_map")
-
-
-def _shard_map(f, *, mesh, in_specs, out_specs, axis_names):
-    if _compat is not None:
-        return _compat.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            axis_names=axis_names, check_vma=False,
-        )
-    return jax.shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        axis_names=axis_names, check_vma=False,
-    )
+__all__ = ["SPMDTrainer", "TrainState", "RunResult", "build_config", "main"]
 
 
 @dataclasses.dataclass
@@ -277,9 +241,7 @@ class SPMDTrainer:
                 f"topology has {topology.n_nodes} nodes but mesh gossip axes "
                 f"{self.gossip_axes} give {self.g}"
             )
-        # Partial-manual shard_map (manual gossip × auto model) needs the
-        # modern manual-axes API; otherwise run the stacked GSPMD engine.
-        self.use_shard_map = self.g > 1 and _has_manual_axes()
+        self.use_shard_map = self.g > 1
         tp = mesh.shape.get("model", 1)
         self.defs = tfm.model_defs(cfg, tp_size=tp)
         self.loss_fn = loss_fn or (lambda p, b: tfm.loss_fn(p, cfg, b))
@@ -444,7 +406,7 @@ class SPMDTrainer:
                 )
             return p, o
 
-        with _set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             p, o = jax.jit(
                 _init, out_shardings=(self.param_shardings, self.opt_shardings)
             )(key)
@@ -515,10 +477,11 @@ class SPMDTrainer:
         fused = self._fused_split(program) if self.g > 1 else None
 
         def node_step(params_st, opt_st, batch_st, lr, fault=None):
+            # batches carry the node axis at every G; params only when G > 1
             squeeze = self.g > 1
             params = jax.tree.map(lambda x: x[0], params_st) if squeeze else params_st
             opt_state = jax.tree.map(lambda x: x[0], opt_st) if squeeze else opt_st
-            batch = jax.tree.map(lambda x: x[0], batch_st) if squeeze else batch_st
+            batch = jax.tree.map(lambda x: x[0], batch_st)
 
             loss, grads = self._grads_of(params, batch)
             norms = (
@@ -828,7 +791,7 @@ class SPMDTrainer:
                 state.params, self.bucket_mb
             )
         layout = self._bucket_layout
-        with _set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             loss, grads, norms = self._bucket_grads_fn(batch)(
                 state.params, batch
             )
@@ -904,42 +867,45 @@ class SPMDTrainer:
         if key in self._step_cache:
             return self._step_cache[key]
 
-        gspec = P(self.gossip_axes) if self.gossip_axes else P()
-        if self.g == 1:
-            fn = jax.jit(
-                self._node_step(program, faulty=faulty),
-                donate_argnums=(0, 1) if self.donate else (),
-            )
-            self._step_cache[key] = fn
-            return fn
-
+        gspec = P(self.gossip_axes) if self.g > 1 else P()
         lead = lambda nd: P(self.gossip_axes, *([None] * nd))
         in_specs = (
             jax.tree.map(lambda l: lead(len(l.shape) - 1), self.abstract_state[0]),
             jax.tree.map(lambda l: lead(len(l.shape) - 1), self.abstract_state[1]),
         )
 
-        def shardings_for(batch_tree):
-            base = (
+        def jit_step(step_fn, batch_tree):
+            ins = (
                 self.param_shardings,
                 self.opt_shardings,
-                jax.tree.map(
-                    lambda x: shd.batch_sharding(
-                        self.mesh, self.gossip_axes, len(x.shape), stacked=True
-                    ),
-                    batch_tree,
-                ),
+                self.batch_shardings(batch_tree),
                 NamedSharding(self.mesh, P()),
             )
             if faulty:  # the runtime-mask pytree is replicated
                 rep = NamedSharding(self.mesh, P())
-                base = base + (
+                ins = ins + (
                     {"update": rep, "alive": rep,
                      "link": rep if self.fault_model.has_link_faults else None},
                 )
-            return base
+            return jax.jit(
+                step_fn,
+                in_shardings=ins,
+                out_shardings=(
+                    self.param_shardings,
+                    self.opt_shardings,
+                    NamedSharding(self.mesh, gspec),
+                    NamedSharding(self.mesh, gspec),
+                ),
+                donate_argnums=(0, 1) if self.donate else (),
+            )
 
-        if self.use_shard_map:
+        if self.g == 1:
+            node_step = self._node_step(program)
+
+            def build(batch_tree):
+                return jit_step(node_step, batch_tree)
+
+        elif self.use_shard_map:
             node_step = self._node_step(program, faulty=faulty)
 
             def build(batch_tree):
@@ -949,40 +915,24 @@ class SPMDTrainer:
                 arg_specs = (in_specs[0], in_specs[1], batch_specs, P())
                 if faulty:
                     arg_specs = arg_specs + (P(),)
-                mapped = _shard_map(
+                mapped = jax.shard_map(
                     node_step,
                     mesh=self.mesh,
                     in_specs=arg_specs,
                     out_specs=(in_specs[0], in_specs[1], gspec, gspec),
-                    axis_names=set(self.gossip_axes),
+                    # size-1 axes are manual too: a Pallas TPU kernel
+                    # (--fused-apply) cannot sit in an auto-partitioned region
+                    axis_names=set(self.gossip_axes)
+                    | {a for a, n in self.mesh.shape.items() if n == 1},
+                    check_vma=False,
                 )
-                return jax.jit(
-                    mapped,
-                    in_shardings=shardings_for(batch_tree),
-                    out_shardings=(
-                        self.param_shardings,
-                        self.opt_shardings,
-                        NamedSharding(self.mesh, gspec),
-                        NamedSharding(self.mesh, gspec),
-                    ),
-                    donate_argnums=(0, 1) if self.donate else (),
-                )
+                return jit_step(mapped, batch_tree)
 
         else:
             stacked_step = self._stacked_step(program, faulty=faulty)
 
             def build(batch_tree):
-                return jax.jit(
-                    stacked_step,
-                    in_shardings=shardings_for(batch_tree),
-                    out_shardings=(
-                        self.param_shardings,
-                        self.opt_shardings,
-                        NamedSharding(self.mesh, gspec),
-                        NamedSharding(self.mesh, gspec),
-                    ),
-                    donate_argnums=(0, 1) if self.donate else (),
-                )
+                return jit_step(stacked_step, batch_tree)
 
         fn = _LazyStep(build)
         self._step_cache[key] = fn
@@ -1028,7 +978,7 @@ class SPMDTrainer:
                 )
                 if tel.active:
                     tel.event("rejoin", state.step, data={"node": int(node)})
-                with _set_mesh(self.mesh):
+                with jax.set_mesh(self.mesh):
                     state = TrainState(
                         adopt_neighbor_average(state.params, node, nbrs),
                         adopt_neighbor_average(state.opt_state, node, nbrs),
@@ -1043,7 +993,7 @@ class SPMDTrainer:
                 )
                 if tel.active:
                     tel.event("depart", state.step, data={"node": int(node)})
-                with _set_mesh(self.mesh):
+                with jax.set_mesh(self.mesh):
                     state = TrainState(
                         drain_handoff(state.params, node, nbrs, fr.alive),
                         drain_handoff(state.opt_state, node, nbrs, fr.alive),
@@ -1063,7 +1013,7 @@ class SPMDTrainer:
                     data={"alive": [bool(b) for b in self._last_membership]},
                 )
         if ctl is not None and self.g > 1 and ctl.should_probe(state.step):
-            with _set_mesh(self.mesh):
+            with jax.set_mesh(self.mesh):
                 if fr is not None:
                     from repro.core.consensus import consensus_distance_masked_jit
 
@@ -1130,7 +1080,7 @@ class SPMDTrainer:
         warm = self._was_warm and (
             not isinstance(fn, _LazyStep) or fn._fn is not None
         )
-        with _set_mesh(self.mesh), self._retrace_guard(
+        with jax.set_mesh(self.mesh), self._retrace_guard(
             warm, f"spmd step {state.step}"
         ):
             p, o, loss, norms = fn(*args)
@@ -1194,12 +1144,7 @@ class SPMDTrainer:
         """Abstract lowering for the dry-run: ShapeDtypeStructs only."""
         from repro.configs.base import input_specs
 
-        batch = input_specs(self.cfg, shape, n_nodes=max(self.g, 1))
-        if self.g == 1:
-            # flat batch for the degenerate placement
-            batch = {
-                k: jax.ShapeDtypeStruct(v.shape, v.dtype) for k, v in batch.items()
-            }
+        batch = input_specs(self.cfg, shape, n_nodes=self.g)
         fn = self.step_fn(epoch, step=step)
         p_abs, o_abs = self.abstract_state
         lr = jax.ShapeDtypeStruct((), jnp.float32)
@@ -1215,45 +1160,66 @@ class SPMDTrainer:
                     else None
                 ),
             },)
-        with _set_mesh(self.mesh):
-            if self.g == 1:
-                lowered = jax.jit(
-                    self._node_step(self._program_at(step, epoch)),
-                    in_shardings=(
-                        self.param_shardings,
-                        self.opt_shardings,
-                        jax.tree.map(
-                            lambda x: shd.batch_sharding(
-                                self.mesh, (), len(x.shape), stacked=False
-                            ),
-                            batch,
-                        ),
-                        NamedSharding(self.mesh, P()),
-                    ),
-                    out_shardings=(
-                        self.param_shardings,
-                        self.opt_shardings,
-                        NamedSharding(self.mesh, P()),
-                        NamedSharding(self.mesh, P()),
-                    ),
-                ).lower(p_abs, o_abs, batch, lr)
-            else:
-                lowered = fn.lower(p_abs, o_abs, batch, lr, *fault_abs)
-        return lowered
+        with jax.set_mesh(self.mesh):
+            return fn.lower(p_abs, o_abs, batch, lr, *fault_abs)
 
 
 # ---------------------------------------------------------------------------
 # CLI launcher:  PYTHONPATH=src python -m repro.launch.train --arch granite-8b
 # ---------------------------------------------------------------------------
 
-def main() -> None:
+@dataclasses.dataclass
+class RunResult:
+    """What one ``main`` run leaves behind: the trainer, its final state,
+    the per-node loss of every step, each step's wall time (ended by
+    ``block_until_ready``; the first includes tracing and compilation), and
+    the learning rate the steps ran at (``--lr`` times its scaling)."""
+
+    trainer: SPMDTrainer
+    state: TrainState
+    losses: list
+    step_seconds: list
+    lr: float
+
+
+def use_repo_compile_cache() -> None:
+    """Keep JAX's persistent compilation cache in ``<repo>/.jax_cache``,
+    unless ``JAX_COMPILATION_CACHE_DIR`` already chose a directory (JAX
+    reads that variable itself).  The path is fixed because it is part of
+    the cache key: a directory that moves never hits."""
+    import os
+    from pathlib import Path
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        repo = Path(__file__).resolve().parents[3]
+        jax.config.update("jax_compilation_cache_dir", str(repo / ".jax_cache"))
+
+
+def build_config(arch: str, *, reduced: bool = False,
+                 layers: Optional[int] = None):
+    """The CLI's model config: ``arch`` at its published size, cut to the
+    CPU-scale reduced config only when ``reduced`` is given, and to
+    ``layers`` layers (depth only) when that is given."""
+    from repro.configs import get_config
+
+    cfg = get_config(arch + ("-reduced" if reduced else ""))
+    cfg = dataclasses.replace(cfg, name=arch)  # keep gossip placement
+    if layers is not None:
+        cfg = dataclasses.replace(cfg, n_layers=layers)
+    return cfg
+
+
+def main(argv: Optional[list] = None) -> RunResult:
     import argparse
-    import time
 
     ap = argparse.ArgumentParser(description="decentralized training launcher")
     ap.add_argument("--arch", default="granite-8b")
     ap.add_argument("--reduced", action="store_true",
-                    help="use the CPU-scale reduced config (default on CPU)")
+                    help="cut widths and depth to the CPU-scale reduced "
+                         "config (ArchConfig.reduced)")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="depth-only cut: replace n_layers, keep the "
+                         "published widths")
     ap.add_argument("--topology", default="d_ada")
     ap.add_argument("--mixing", default="ppermute", choices=["ppermute", "dense"])
     ap.add_argument("--mix-every", type=int, default=1)
@@ -1330,6 +1296,9 @@ def main() -> None:
     ap.add_argument("--k-floor", default="2",
                     help="Ada decay floor: an int, or 'one_peer' for the "
                          "time-varying one-peer exponential family")
+    ap.add_argument("--gamma-k", type=float, default=None,
+                    help="Ada decay rate per epoch (default: the paper's "
+                         "0.02; 1.0 is its ResNet50 @ 1008 GPUs setting)")
     ap.add_argument("--consensus-target", type=float, default=None,
                     help="close the Ada loop: step the schedule down a rung "
                          "whenever measured consensus distance falls to this "
@@ -1373,11 +1342,8 @@ def main() -> None:
                     help="gauge/variance emission cadence in steps "
                          "(with --telemetry; spans and counters are "
                          "per-step)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
-    import jax
-
-    from repro.configs import get_config
     from repro.core.dsgd import make_topology
     from repro.data import SyntheticLM
     from repro.launch.mesh import make_mesh
@@ -1385,17 +1351,19 @@ def main() -> None:
     from repro.optim.sgd import get_optimizer
 
     shape = tuple(int(x) for x in args.mesh.split(","))
-    if len(jax.devices()) < shape[0] * shape[1]:
+    need, found = shape[0] * shape[1], len(jax.devices())
+    if found < need:
+        backend = jax.default_backend()
+        hint = (
+            f" — set XLA_FLAGS=--xla_force_host_platform_device_count={need}"
+            if backend == "cpu" else ""
+        )
         raise SystemExit(
-            f"mesh {shape} needs {shape[0]*shape[1]} devices but only "
-            f"{len(jax.devices())} present — set XLA_FLAGS="
-            f"--xla_force_host_platform_device_count={shape[0]*shape[1]}"
+            f"mesh {shape} needs {need} devices but only {found} {backend} "
+            f"device(s) present{hint}"
         )
     mesh = make_mesh(shape, ("data", "model"))
-    cfg = get_config(args.arch + ("-reduced" if args.reduced or jax.default_backend() == "cpu" else ""))
-    import dataclasses
-
-    cfg = dataclasses.replace(cfg, name=args.arch)  # keep gossip placement
+    cfg = build_config(args.arch, reduced=args.reduced, layers=args.layers)
     g = shape[0]
     if args.k_floor == "one_peer":
         k_floor = "one_peer"
@@ -1422,7 +1390,7 @@ def main() -> None:
         deadline_backoff=args.deadline_backoff,
     )
     topo = make_topology(
-        args.topology, g, k_floor=k_floor,
+        args.topology, g, k_floor=k_floor, gamma_k=args.gamma_k,
         consensus_target=args.consensus_target,
         consensus_spike=args.consensus_spike,
         consensus_probe_every=args.consensus_every,
@@ -1440,9 +1408,14 @@ def main() -> None:
         cfg, mesh, topo, get_optimizer(args.optimizer), collect_norms=True,
         mixing=args.mixing, mix_every=args.mix_every,
         mix_rounds=args.mix_rounds, hub_balance=args.hub_balance,
-        fused_apply=args.fused_apply, donate=False,
+        fused_apply=args.fused_apply,
         bucket_mb=args.bucket_mb, telemetry=recorder,
     )
+    n_params = sum(
+        int(np.prod(x.shape)) for x in jax.tree.leaves(abstract_params(trainer.defs))
+    )
+    print(f"{args.arch}: {cfg.n_layers} layers, d_model {cfg.d_model}, "
+          f"d_ff {cfg.d_ff}, vocab {cfg.vocab}, {n_params:,} params per node")
     if recorder is not None:
         run = {
             "engine": "spmd",
@@ -1499,11 +1472,15 @@ def main() -> None:
         args.lr_scaling, global_batch=g * args.per_node_batch,
         base_batch=max(g * args.per_node_batch, 1), graph_degree=topo.degree_at(0),
     )
-    t0 = time.time()
+    losses, step_seconds = [], []
     for t in range(start_step, args.steps):
         batch = {k: jnp.asarray(v) for k, v in src.stacked(g, t, args.per_node_batch).items()}
         epoch = t // args.steps_per_epoch
+        t0 = time.perf_counter()
         state, loss, norms = trainer.train_step(state, batch, args.lr * scale, epoch=epoch)
+        jax.block_until_ready((state.params, loss))
+        step_seconds.append(time.perf_counter() - t0)
+        losses.append(np.asarray(loss).reshape(-1))
         if t % 5 == 0 or t == args.steps - 1:
             print(f"step {t:4d} k={topo.degree_at(epoch, t)} loss={float(loss.mean()):.4f} "
                   f"spread={float(loss.max() - loss.min()):.4f}")
@@ -1518,7 +1495,7 @@ def main() -> None:
             trainer.telemetry.event(
                 "checkpoint_save", t + 1, data={"dir": args.ckpt_dir}
             )
-    print(f"{args.steps} steps in {time.time()-t0:.1f}s")
+    print(f"{len(step_seconds)} steps in {sum(step_seconds):.1f}s")
     if trainer.round_ms:
         ms = np.asarray(trainer.round_ms)
         line = (f"round trace: median {np.median(ms):.1f}ms "
@@ -1539,7 +1516,9 @@ def main() -> None:
         trainer.telemetry.close()
         print(f"telemetry: {args.telemetry} "
               f"(python -m repro.telemetry summarize {args.telemetry})")
+    return RunResult(trainer, state, losses, step_seconds, args.lr * scale)
 
 
 if __name__ == "__main__":
+    use_repo_compile_cache()
     main()

@@ -5,8 +5,9 @@ Implementations (selected by ``impl``):
   * "chunked"   — online-softmax over KV chunks via ``lax.scan`` (the flash
     algorithm expressed in XLA): O(chunk) score memory, CPU-compilable.
     Used for the 32k shapes in the dry-run.
-  * the Pallas TPU kernel lives in ``repro.kernels.flash_attention`` and is
-    selected by the launcher on TPU backends (``cfg.attn_impl = "pallas"``).
+  * the Pallas TPU kernel lives in ``repro.kernels.flash_attention``; no
+    config or launcher selects it yet, so ``cfg.attn_impl`` picks an XLA
+    path on every backend.
 
 Supports causal masking, sliding windows (the long-context carve-in for
 full-attention archs on ``long_500k``), GQA head grouping, and single-token

@@ -29,15 +29,15 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core.buckets import BucketLayout, build_bucket_step
 from repro.core.graphs import Ring
 from repro.core.schedule import compile_graph
 from repro.launch.hlo_analysis import assert_no_all_gather, collective_counts
+from repro.launch.mesh import make_mesh
 from repro.optim.sgd import sgd
 
 N = 8
-mesh = compat.make_mesh((N,), ("gossip",))
+mesh = make_mesh((N,), ("gossip",))
 
 # --- 1. bucketed shard interpreter: permutes only, ops x buckets ------------
 prog = compile_graph(Ring(N))
@@ -51,9 +51,9 @@ x = {
     "b": rng.normal(size=(N, 17)).astype(np.float32),
 }
 f = jax.jit(
-    compat.shard_map(
+    jax.shard_map(
         lambda v: prog.apply_shard_bucketed(v, "gossip", layout),
-        mesh=mesh, in_specs=P("gossip"), out_specs=P("gossip"),
+        mesh=mesh, check_vma=False, in_specs=P("gossip"), out_specs=P("gossip"),
     )
 )
 xj = jax.tree.map(jnp.asarray, x)

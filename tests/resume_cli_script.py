@@ -30,8 +30,7 @@ from repro.launch import train
 
 
 def run(argv):
-    sys.argv = ["train"] + argv
-    train.main()
+    train.main(argv)
 
 
 base = tempfile.mkdtemp(prefix="resume_cli_")

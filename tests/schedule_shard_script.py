@@ -21,7 +21,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core.graphs import (
     Complete, Exponential, Ring, RingLattice, Star, Torus,
     one_peer_exponential, random_matching,
@@ -29,9 +28,10 @@ from repro.core.graphs import (
 from repro.core.schedule import (
     AllReduce, GatherRow, PPermute, compile_graph, dense_program,
 )
+from repro.launch.mesh import make_mesh
 
 N = 8
-mesh = compat.make_mesh((N,), ("gossip",))
+mesh = make_mesh((N,), ("gossip",))
 x = np.random.default_rng(0).normal(size=(N, 4, 3)).astype(np.float32)
 
 graphs = [
@@ -44,9 +44,9 @@ oracles = [g.mixing_matrix() for g in graphs] + [Ring(N).mixing_matrix()]
 
 failures = []
 for prog, w in zip(programs, oracles):
-    f = compat.shard_map(
+    f = jax.shard_map(
         lambda v: prog.apply_shard(v, "gossip"),
-        mesh=mesh, in_specs=P("gossip"), out_specs=P("gossip"),
+        mesh=mesh, check_vma=False, in_specs=P("gossip"), out_specs=P("gossip"),
     )
     jf = jax.jit(f)
     got = np.asarray(jf(jnp.asarray(x)))
@@ -91,12 +91,12 @@ tree = {
 }
 xi_stacked = float(consensus_distance_stacked(tree))
 f_xi = jax.jit(
-    compat.shard_map(
+    jax.shard_map(
         lambda v: (
             consensus_distance_shard(v, "gossip")[None],
             consensus_sq_shard(v, "gossip")[None],
         ),
-        mesh=mesh,
+        mesh=mesh, check_vma=False,
         in_specs=P("gossip"),
         out_specs=(P("gossip"), P("gossip")),
     )

@@ -78,7 +78,7 @@ def test_gossip_update_sweep(p, deg, block, dtype):
 def test_gossip_update_runtime_lr_beta_no_recompile():
     """LR schedules must not retrigger compiles: lr/beta ride in SMEM at
     runtime, so sweeping them leaves exactly one cached executable."""
-    from repro.kernels.gossip_update import _gossip_update
+    from repro.kernels.gossip_update import _leaf_update
 
     ks = jax.random.split(jax.random.PRNGKey(3), 4)
     theta = jax.random.normal(ks[0], (512,))
@@ -86,13 +86,13 @@ def test_gossip_update_runtime_lr_beta_no_recompile():
     w = jnp.full((3,), 1.0 / 3)
     g = jax.random.normal(ks[2], (512,))
     m = jax.random.normal(ks[3], (512,))
-    _gossip_update._clear_cache()
+    _leaf_update._clear_cache()
     for lr, beta in [(0.1, 0.9), (0.05, 0.9), (0.01, 0.8), (0.2, 0.0)]:
         o, mm = ops.gossip_update(theta, nbr, w, g, m, lr=lr, beta=beta, block=256)
         o2, m2 = ref.gossip_update_ref(theta, nbr, w, g, m, lr=lr, beta=beta)
         np.testing.assert_allclose(np.asarray(o), np.asarray(o2), atol=1e-5)
         np.testing.assert_allclose(np.asarray(mm), np.asarray(m2), atol=1e-5)
-    assert _gossip_update._cache_size() == 1
+    assert _leaf_update._cache_size() == 1
 
 
 @pytest.mark.parametrize("graph_name", ["star", "ring", "one_peer", "matching", "irregular"])

@@ -1,0 +1,149 @@
+"""The fused gossip kernels compile for a TPU v5e chip.
+
+Interpret mode accepts tilings the chip's compiler refuses, so each kernel
+of the gossip path is compiled here for a described (not attached) v5e at
+the compiled default block (1024), in float32 and bfloat16, at program
+degrees 1 and 2: the stacked kernels over one 4096 x 14336 MLP matrix per
+node, the one-node ``gossip_update`` over a leaf that tiles in place and
+one with an odd vocab.  The HLO must hold the Mosaic ``tpu_custom_call``:
+the kernel was compiled, not interpreted, and its tile passed the VMEM
+budget check, which only compiled mode applies.  ``fused_apply_shard``,
+the four-chip trainer's ``--fused-apply`` round, is compiled inside
+``shard_map`` over the described 2x2 chips on the same two leaves.
+
+The topology is described inside a module fixture, never at import: only
+one process may load the TPU compiler library, and every test worker
+imports this file.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as PS
+
+from repro.core.graphs import Ring, one_peer_exponential
+from repro.core.schedule import compile_graph
+from repro.kernels.gossip_update import (
+    fused_apply_shard, fused_bucket_update, gossip_program_update,
+    gossip_update,
+)
+
+N = 4
+P = 4096 * 14336  # one granite-8b MLP matrix per node
+BLOCK = 1024
+DTYPES = [jnp.float32, jnp.bfloat16]
+# one node's leaves: granite-8b's 4-layer stacked MLP matrix, which tiles in
+# place, and internvl2-2b's output head, whose odd vocab (92553) does not
+# and goes through the padded view
+LEAVES = {"mlp": (4, 4096, 14336), "odd_vocab_head": (2048, 92553)}
+# degree 1: one hop of the one-peer exponential graph; degree 2: the ring
+PROGRAMS = {1: lambda: compile_graph(one_peer_exponential(N, 0)),
+            2: lambda: compile_graph(Ring(N))}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler library in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache out of these tests
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled_hlo(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+@pytest.mark.parametrize("leaf", LEAVES, ids=list(LEAVES))
+@pytest.mark.parametrize("deg", [1, 2])
+@pytest.mark.parametrize("dtype", DTYPES, ids=lambda d: jnp.dtype(d).name)
+def test_gossip_update_compiles(one_chip, dtype, deg, leaf):
+    shape = LEAVES[leaf]
+    s = lambda shape, dt=dtype: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    fn = functools.partial(
+        gossip_update, lr=0.1, beta=0.9, block=BLOCK, interpret=False
+    )
+    hlo = _compiled_hlo(
+        lambda th, nb, w, g, m: fn(th, nb, w, g, m),
+        s(shape), (s(shape),) * deg, s((deg + 1,), jnp.float32), s(shape),
+        s(shape, jnp.float32),
+    )
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("deg", [1, 2])
+@pytest.mark.parametrize("dtype", DTYPES, ids=lambda d: jnp.dtype(d).name)
+def test_gossip_program_update_compiles(one_chip, dtype, deg):
+    s = lambda shape, dt=dtype: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def fn(th, nb, w, f, g, m):
+        return gossip_program_update(
+            th, nb, w, g, m, lr=0.1, beta=0.9, fault=f, block=BLOCK,
+            interpret=False,
+        )
+
+    hlo = _compiled_hlo(
+        fn, s((N, P)), (s((N, P)),) * deg, s((N, deg + 1), jnp.float32),
+        s((N, deg + 1), jnp.float32), s((N, P)), s((N, P), jnp.float32),
+    )
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("deg", [1, 2])
+@pytest.mark.parametrize("dtype", DTYPES, ids=lambda d: jnp.dtype(d).name)
+def test_fused_bucket_update_compiles(one_chip, dtype, deg):
+    program = PROGRAMS[deg]()
+    assert program.permute_tables()[0].shape == (N, deg)
+    s = lambda shape, dt=dtype: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def fn(th, g, m):
+        return fused_bucket_update(
+            program, th, g, m, lr=0.1, beta=0.9, block=BLOCK, interpret=False
+        )
+
+    hlo = _compiled_hlo(fn, s((N, P)), s((N, P)), s((N, P), jnp.float32))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("leaf", LEAVES, ids=list(LEAVES))
+@pytest.mark.parametrize("deg", [1, 2])
+def test_fused_apply_shard_compiles(topo, deg, leaf):
+    mesh = Mesh(np.array(topo.devices).reshape(N, 1), ("data", "model"))
+    program = PROGRAMS[deg]()
+    leaf = (1,) + LEAVES[leaf]  # this node's slice of the stacked leaf
+    spec = PS(("data", "model"))
+
+    def node(th, g, m):
+        return fused_apply_shard(
+            program, th, g, m, "data", lr=0.1, beta=0.9, interpret=False
+        )
+
+    fn = jax.shard_map(
+        node, mesh=mesh, in_specs=(spec,) * 3, out_specs=(spec, spec),
+        axis_names={"data", "model"}, check_vma=False,
+    )
+    s = lambda dt: jax.ShapeDtypeStruct(
+        (N,) + leaf[1:], dt, sharding=NamedSharding(mesh, spec)
+    )
+    hlo = _compiled_hlo(fn, s(jnp.bfloat16), s(jnp.bfloat16), s(jnp.float32))
+    assert "tpu_custom_call" in hlo
+    assert "collective-permute" in hlo

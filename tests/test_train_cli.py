@@ -1,0 +1,71 @@
+"""The training launcher, run in process through ``main(argv)``.
+
+One gossip node (``--mesh 1,1``) is the one-chip placement: the step runs
+the node's local update on batches that still carry the node axis.  The
+model's size comes from the command line alone, never from the backend.
+"""
+import dataclasses
+
+import jax
+import numpy as np
+import pytest
+
+from repro.configs import get_config
+from repro.launch.train import build_config, main
+
+ONE_NODE = [
+    "--arch", "granite-8b", "--reduced", "--mesh", "1,1",
+    "--topology", "d_ring", "--steps", "3", "--steps-per-epoch", "3",
+    "--seq", "16", "--per-node-batch", "2",
+]
+
+
+def _checksums(tree):
+    return [float(np.sum(np.square(np.asarray(x, np.float32))))
+            for x in jax.tree.leaves(tree)]
+
+
+def test_one_node_mesh_trains(capsys):
+    res = main(ONE_NODE)
+    assert len(res.losses) == len(res.step_seconds) == 3
+    assert all(l.shape == (1,) and np.isfinite(l).all() for l in res.losses)
+    assert not res.trainer.use_shard_map
+    init = res.trainer.init_state(jax.random.PRNGKey(0))
+    assert _checksums(res.state.params) != _checksums(init.params)
+    out = capsys.readouterr().out
+    assert "granite-8b: 2 layers, d_model 256" in out
+    assert "3 steps in" in out
+
+
+def test_layers_flag_sets_depth_in_the_run(capsys):
+    res = main(ONE_NODE + ["--steps", "1", "--layers", "1"])
+    assert res.trainer.cfg.n_layers == 1
+    assert "granite-8b: 1 layers" in capsys.readouterr().out
+
+
+@pytest.mark.parametrize("reduced", [False, True])
+@pytest.mark.parametrize("layers", [1, 4])
+def test_layers_changes_only_n_layers(reduced, layers):
+    full = build_config("granite-8b", reduced=reduced)
+    cut = build_config("granite-8b", reduced=reduced, layers=layers)
+    assert cut.n_layers == layers
+    assert dataclasses.replace(cut, n_layers=full.n_layers) == full
+
+
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_config_does_not_depend_on_backend(monkeypatch, backend):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    cfg = build_config("granite-8b")
+    published = get_config("granite-8b")
+    assert cfg == published
+    assert (cfg.d_model, cfg.n_heads, cfg.n_kv, cfg.d_ff, cfg.vocab) == (
+        4096, 32, 8, 14336, 49152
+    )
+    assert build_config("granite-8b", reduced=True) == dataclasses.replace(
+        get_config("granite-8b-reduced"), name="granite-8b"
+    )
+
+
+def test_mesh_larger_than_devices_names_the_fix():
+    with pytest.raises(SystemExit, match="xla_force_host_platform_device_count=2"):
+        main(ONE_NODE + ["--mesh", "2,1"])
