@@ -1,0 +1,11 @@
+"""step.optimizer_ms: device self time per step, in ms, of the optimizer
+update (the program's ``optimizer`` scope), averaged over the cell's
+chips. ``benchlib/scopes.py`` joins the trace's operations to the step's
+scopes."""
+from pathlib import Path
+
+from benchlib import scopes
+
+
+def read(ctx):
+    return scopes.phase_ms(ctx, Path(__file__).resolve().parents[1], "optimizer")

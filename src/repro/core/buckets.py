@@ -55,6 +55,8 @@ from typing import Any, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from repro.telemetry import profile
+
 PyTree = Any
 
 __all__ = [
@@ -311,6 +313,7 @@ def xi_from_folded_sq(folded_sq) -> float:
     return float(np.sqrt(np.mean(sq))) if sq.size else 0.0
 
 
+@profile.scope("probe")
 def _bucket_partial_sq(out_mat: jax.Array) -> jax.Array:
     """This bucket's per-node partial Σ_c (x_ic - x̄_c)² — (n,) float32.
 
@@ -377,13 +380,15 @@ def build_bucket_step(
     if kernel_split is not None and (wd or nesterov):
         raise ValueError("the fused kernel path supports plain momentum-SGD only")
 
-    def _mix(mat, fault):
+    @profile.scope("gossip")
+    def _mix(mat, fault, stage=program):
         if faulty:
-            return program.apply_masked(
+            return stage.apply_masked(
                 mat, fault["alive"], link_up=fault.get("link")
             )
-        return program.apply_stacked(mat)
+        return stage.apply_stacked(mat)
 
+    @profile.scope("optimizer")
     def _update(theta, mom, grad, lr, fault):
         """Elementwise SGD on one bucket matrix; returns (theta*, mom')."""
         t32 = theta.astype(jnp.float32)
@@ -408,18 +413,13 @@ def build_bucket_step(
         from repro.kernels.gossip_update import fused_bucket_update
 
         first, rest = kernel_split
-        t_new, m_new = fused_bucket_update(
-            first, theta, grad, mom,
-            lr=lr, beta=beta, fault=fault, mix_order="post",
-        )
-        for stage in rest:
-            t_new = (
-                stage.apply_masked(
-                    t_new, fault["alive"], link_up=fault.get("link")
-                )
-                if faulty
-                else stage.apply_stacked(t_new)
+        with profile.scope("fused_update"):
+            t_new, m_new = fused_bucket_update(
+                first, theta, grad, mom,
+                lr=lr, beta=beta, fault=fault, mix_order="post",
             )
+        for stage in rest:
+            t_new = _mix(t_new, fault, stage)
         return t_new, m_new
 
     def bucket_step(theta_b, mom_b, grad_b, lr, tok, fault=None):

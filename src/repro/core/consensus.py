@@ -53,6 +53,7 @@ from repro.core.ada import AdaSchedule
 from repro.core.graphs import (
     CommGraph, RingLattice, one_peer_exponential, one_peer_period,
 )
+from repro.telemetry import profile
 
 PyTree = Any
 
@@ -91,6 +92,7 @@ def consensus_sq_stacked(stacked: PyTree) -> jax.Array:
     return total
 
 
+@profile.scope("probe")
 def consensus_distance_stacked(stacked: PyTree) -> jax.Array:
     """Ξ = sqrt(1/n Σ_i ‖x_i - x̄‖²) over the leading node axis (scalar)."""
     return jnp.sqrt(jnp.mean(consensus_sq_stacked(stacked)))
@@ -102,6 +104,7 @@ def consensus_distance_stacked(stacked: PyTree) -> jax.Array:
 consensus_distance_jit = jax.jit(consensus_distance_stacked)
 
 
+@profile.scope("probe")
 def consensus_distance_masked(stacked: PyTree, alive) -> jax.Array:
     """Ξ over the *alive* nodes only: sqrt(1/|A| Σ_{i∈A} ‖x_i - x̄_A‖²).
 
@@ -143,6 +146,7 @@ def consensus_sq_shard(local: PyTree, axis_names) -> jax.Array:
     return total
 
 
+@profile.scope("probe")
 def consensus_distance_shard(local: PyTree, axis_names) -> jax.Array:
     """Ξ inside ``shard_map``: the same scalar on every node (two pmeans)."""
     return jnp.sqrt(

@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.telemetry import profile
+
 PyTree = Any
 
 __all__ = [
@@ -48,10 +50,11 @@ def param_l2_norms(params: PyTree) -> jax.Array:
     Used inside the per-node step function (so under vmap/shard_map the
     result gains the node axis automatically).
     """
-    leaves = jax.tree.leaves(params)
-    return jnp.stack(
-        [jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32)))) for x in leaves]
-    )
+    with profile.scope("norms"):
+        leaves = jax.tree.leaves(params)
+        return jnp.stack(
+            [jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32)))) for x in leaves]
+        )
 
 
 # ---------------------------------------------------------------------------

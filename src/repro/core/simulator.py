@@ -507,7 +507,6 @@ class DecentralizedSimulator:
         out_t, out_m = [], []
         window: deque = deque()
         for b, w in enumerate(layout.widths):
-            tb = self.telemetry.span_start()
             if len(window) >= MAX_INFLIGHT_BUCKETS:
                 jax.block_until_ready(window.popleft())
             fn = self._bucket_fn(program, w, has_m, fault is not None)
@@ -527,7 +526,6 @@ class DecentralizedSimulator:
                 t2, tok = res
             out_t.append(t2)
             window.append(tok)
-            self.telemetry.bucket_span(tb, step=state.step, index=b)
         new_params = self._place(layout.merge_stacked(out_t, state.params))
         new_opt = (
             self._place(layout.merge_stacked(out_m, state.opt_state))

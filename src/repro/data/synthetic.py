@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.telemetry import profile
+
 __all__ = ["SyntheticLM", "node_batch_iterator"]
 
 
@@ -58,9 +60,11 @@ class SyntheticLM:
         return {"tokens": tokens, "targets": targets}
 
     def stacked(self, n_nodes: int, step: int, per_node_batch: int) -> dict[str, np.ndarray]:
-        """Disjoint shards for all nodes, stacked (n_nodes, B, S)."""
-        outs = [self.sample(i, step, per_node_batch) for i in range(n_nodes)]
-        return {k: np.stack([o[k] for o in outs]) for k in outs[0]}
+        """Disjoint shards for all nodes, stacked (n_nodes, B, S); inside
+        the host span ``repro.data.rows``."""
+        with profile.span("data.rows"):
+            outs = [self.sample(i, step, per_node_batch) for i in range(n_nodes)]
+            return {k: np.stack([o[k] for o in outs]) for k in outs[0]}
 
 
 def node_batch_iterator(

@@ -132,5 +132,6 @@ def flash_attention(
             pltpu.VMEM((bq, d), jnp.float32),  # output accumulator
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)
     return out

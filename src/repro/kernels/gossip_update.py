@@ -233,6 +233,7 @@ def _leaf_update(theta, neighbors, weights, fault, grad, momentum, scalars,
         # in place: theta -> theta', momentum -> m' (the tiles are disjoint)
         input_output_aliases={3: 0, 5 + len(neighbors): 1},
         interpret=interpret,
+        name="gossip_leaf_update",
     )(scalars, weights.astype(jnp.float32), fault.astype(jnp.float32),
       view(theta), *map(view, neighbors), view(grad), view(momentum))
     return unview(out), unview(m_new)
@@ -312,6 +313,7 @@ def _gossip_program_update(theta, neighbors, weights, fault, grad, momentum,
             jax.ShapeDtypeStruct((n, p), jnp.float32),
         ],
         interpret=interpret,
+        name="gossip_program_update",
     )(scalars, weights.astype(jnp.float32), fault.astype(jnp.float32),
       theta, *neighbors, grad, momentum)
 

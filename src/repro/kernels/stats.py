@@ -56,4 +56,5 @@ def l2_norms(x: jax.Array, *, block: int = 2048,
         out_shape=jax.ShapeDtypeStruct((r,), jnp.float32),
         scratch_shapes=[pltpu.SMEM((1,), jnp.float32)],
         interpret=interpret,
+        name="l2_norms",
     )(x)

@@ -57,6 +57,7 @@ from repro.launch.mesh import gossip_axes_for, gossip_size
 from repro.models import transformer as tfm
 from repro.models.common import abstract_params, spec_tree
 from repro.optim.sgd import Optimizer
+from repro.telemetry import profile
 
 PyTree = Any
 
@@ -71,19 +72,37 @@ class TrainState:
 
 
 class _LazyStep:
-    """Defers the jit/shard_map build until concrete batch shapes arrive."""
+    """Defers the jit/shard_map build until concrete batch shapes arrive.
+    Keeps the shapes and dtypes of its first call's arguments, so that
+    ``hlo_text`` can name the executable that call built."""
 
-    def __init__(self, build):
+    def __init__(self, build, mesh):
         self._build = build
+        self._mesh = mesh
         self._fn = None
+        self.abstract_args = None
 
     def __call__(self, params, opt_state, batch, lr, *fault):
         if self._fn is None:
             self._fn = self._build(batch)
+            self.abstract_args = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(
+                    np.shape(x), x.dtype, weak_type=getattr(x, "weak_type", False)
+                ),
+                (params, opt_state, batch, lr) + fault,
+            )
         return self._fn(params, opt_state, batch, lr, *fault)
 
     def lower(self, params, opt_state, batch, lr, *fault):
         return self._build(batch).lower(params, opt_state, batch, lr, *fault)
+
+    def hlo_text(self, *args) -> str:
+        """The optimized HLO text of the executable for ``args`` (arrays or
+        ShapeDtypeStructs; by default those of the first call), lowered and
+        compiled again: a load from the persistent compile cache where the
+        step was cached.  The jit's in_shardings give the shardings."""
+        with jax.set_mesh(self._mesh):
+            return self.lower(*(args or self.abstract_args)).compile().as_text()
 
 
 class SPMDTrainer:
@@ -416,13 +435,15 @@ class SPMDTrainer:
     def _grads_of(self, params, batch):
         accum = self.accum_steps
         if accum == 1:
-            return jax.value_and_grad(self.loss_fn)(params, batch)
+            with profile.scope("model"):
+                return jax.value_and_grad(self.loss_fn)(params, batch)
         micro = jax.tree.map(
             lambda x: x.reshape((accum, x.shape[0] // accum) + x.shape[1:]), batch
         )
 
         def acc_body(carry, mb):
-            l, g = jax.value_and_grad(self.loss_fn)(params, mb)
+            with profile.scope("model"):
+                l, g = jax.value_and_grad(self.loss_fn)(params, mb)
             return (
                 carry[0] + l / accum,
                 jax.tree.map(lambda a, b: a + b / accum, carry[1], g),
@@ -490,10 +511,11 @@ class SPMDTrainer:
                 else jnp.zeros((0,), jnp.float32)
             )
 
-            def _mix(tree):
+            @profile.scope("gossip")
+            def _mix(tree, stage=program):
                 if fault is None:
-                    return program.apply_shard(tree, axes)
-                return program.apply_shard_masked(
+                    return stage.apply_shard(tree, axes)
+                return stage.apply_shard_masked(
                     tree, axes, fault["alive"], link_up=fault["link"]
                 )
 
@@ -503,22 +525,19 @@ class SPMDTrainer:
                 from repro.kernels.gossip_update import fused_apply_shard
 
                 first, rest = fused
-                new_p, new_o = fused_apply_shard(
-                    first, params, grads, opt_state, axes,
-                    lr=lr, beta=self._fused_beta, fault=fault,
-                    mix_order=topo.mix_order,
-                )
+                with profile.scope("fused_update"):
+                    new_p, new_o = fused_apply_shard(
+                        first, params, grads, opt_state, axes,
+                        lr=lr, beta=self._fused_beta, fault=fault,
+                        mix_order=topo.mix_order,
+                    )
                 for stage in rest:
-                    if fault is None:
-                        new_p = stage.apply_shard(new_p, axes)
-                    else:
-                        new_p = stage.apply_shard_masked(
-                            new_p, axes, fault["alive"], link_up=fault["link"]
-                        )
+                    new_p = _mix(new_p, stage)
             else:
                 if topo.mix_order == "pre" and program is not None and self.g > 1:
                     params = _mix(params)
-                new_p, new_o = opt.update(grads, opt_state, params, lr)
+                with profile.scope("optimizer"):
+                    new_p, new_o = opt.update(grads, opt_state, params, lr)
                 if fault is not None:
                     # stragglers/dead skip their local update (this node's
                     # flag selected from the replicated mask)
@@ -567,10 +586,11 @@ class SPMDTrainer:
                     grads,
                 )
 
-            def _mix(tree):
+            @profile.scope("gossip")
+            def _mix(tree, stage=program):
                 if fault is None:
-                    return program.apply_stacked(tree)
-                return program.apply_masked(
+                    return stage.apply_stacked(tree)
+                return stage.apply_masked(
                     tree, fault["alive"], link_up=fault["link"]
                 )
 
@@ -578,24 +598,21 @@ class SPMDTrainer:
                 from repro.kernels.gossip_update import fused_apply_stacked
 
                 first, rest = fused
-                new_p, new_o = fused_apply_stacked(
-                    first, params, grads, opt_state,
-                    lr=lr, beta=self._fused_beta, fault=fault,
-                    mix_order=topo.mix_order,
-                )
+                with profile.scope("fused_update"):
+                    new_p, new_o = fused_apply_stacked(
+                        first, params, grads, opt_state,
+                        lr=lr, beta=self._fused_beta, fault=fault,
+                        mix_order=topo.mix_order,
+                    )
                 for stage in rest:
-                    if fault is None:
-                        new_p = stage.apply_stacked(new_p)
-                    else:
-                        new_p = stage.apply_masked(
-                            new_p, fault["alive"], link_up=fault["link"]
-                        )
+                    new_p = _mix(new_p, stage)
                 return new_p, new_o, loss, norms
             if topo.mix_order == "pre" and program is not None:
                 params = _mix(params)
-            new_p, new_o = jax.vmap(opt.update, in_axes=(0, 0, 0, None))(
-                grads, opt_state, params, lr
-            )
+            with profile.scope("optimizer"):
+                new_p, new_o = jax.vmap(opt.update, in_axes=(0, 0, 0, None))(
+                    grads, opt_state, params, lr
+                )
             if fault is not None:
                 u = fault["update"]
 
@@ -811,7 +828,6 @@ class SPMDTrainer:
             out_t, out_m = [], []
             window: deque = deque()
             for b, w in enumerate(layout.widths):
-                tb = self.telemetry.span_start()
                 if len(window) >= MAX_INFLIGHT_BUCKETS:
                     jax.block_until_ready(window.popleft())
                 fn = self._bucket_fn(program, w, has_m, fault is not None)
@@ -830,7 +846,6 @@ class SPMDTrainer:
                     t2, tok = res
                 out_t.append(t2)
                 window.append(tok)
-                self.telemetry.bucket_span(tb, step=state.step, index=b)
             new_params, new_opt, tok = self._bucket_merge_fn(state, has_m)(
                 out_t, out_m, tok
             )
@@ -934,9 +949,20 @@ class SPMDTrainer:
             def build(batch_tree):
                 return jit_step(stacked_step, batch_tree)
 
-        fn = _LazyStep(build)
+        fn = _LazyStep(build, self.mesh)
         self._step_cache[key] = fn
         return fn
+
+    def step_hlo_texts(self) -> dict:
+        """``{executable key: optimized HLO text}`` of each step executable
+        this trainer has run, compiled again from its first call's shapes.
+        Each instruction's ``metadata={op_name=...}`` carries the scopes of
+        ``repro.telemetry.profile``, which a profiler trace's events lack.
+        Nothing runs on the step's path for this."""
+        return {
+            repr(key): fn.hlo_text() for key, fn in self._step_cache.items()
+            if isinstance(fn, _LazyStep) and fn.abstract_args is not None
+        }
 
     # -- public API ------------------------------------------------------------------
     def _finish_round(self, loss, norms, t_start, *, step: int, mix: bool,
@@ -959,133 +985,168 @@ class SPMDTrainer:
             )
             self._pending_grads = None
 
-    def train_step(self, state: TrainState, batch: PyTree, lr: float, *, epoch: int = 0):
+    def _realize_faults(self, state: TrainState, epoch: int):
+        """This step's fault realization, applied to the state: recovered
+        nodes rejoin from their neighbours' average, preempted nodes hand
+        off and leave, and membership changes re-arm the controller.
+        Returns (realization, state)."""
+        from repro.core.faults import (
+            adopt_neighbor_average, drain_handoff, rejoin_neighbors,
+            track_membership,
+        )
+
         tel = self.telemetry
-        t_start = tel.round_start()
-        ctl = self.topology.controller
-        fr = None
-        if self.fault_model is not None and self.g > 1:
-            from repro.core.faults import (
-                adopt_neighbor_average, drain_handoff, rejoin_neighbors,
-                track_membership,
+        fr = self.fault_model.at(state.step)
+        for node in fr.rejoin:
+            nbrs = rejoin_neighbors(
+                self.topology, fr, node, step=state.step, epoch=epoch,
+                mix_every=self.mix_every,
             )
-
-            fr = self.fault_model.at(state.step)
-            for node in fr.rejoin:
-                nbrs = rejoin_neighbors(
-                    self.topology, fr, node, step=state.step, epoch=epoch,
-                    mix_every=self.mix_every,
-                )
-                if tel.active:
-                    tel.event("rejoin", state.step, data={"node": int(node)})
-                with jax.set_mesh(self.mesh):
-                    state = TrainState(
-                        adopt_neighbor_average(state.params, node, nbrs),
-                        adopt_neighbor_average(state.opt_state, node, nbrs),
-                        state.step,
-                    )
-            for node in fr.depart:
-                # clean preemption departure: exact mean-preserving handoff
-                # to the neighborhood before the node's row goes dead
-                nbrs = rejoin_neighbors(
-                    self.topology, fr, node, step=state.step, epoch=epoch,
-                    mix_every=self.mix_every,
-                )
-                if tel.active:
-                    tel.event("depart", state.step, data={"node": int(node)})
-                with jax.set_mesh(self.mesh):
-                    state = TrainState(
-                        drain_handoff(state.params, node, nbrs, fr.alive),
-                        drain_handoff(state.opt_state, node, nbrs, fr.alive),
-                        state.step,
-                    )
-            prev_membership = self._last_membership
-            self._last_membership = track_membership(
-                self._last_membership, fr, ctl, state.step
-            )
-            if (
-                tel.active
-                and prev_membership is not None
-                and self._last_membership != prev_membership
-            ):
-                tel.event(
-                    "membership", state.step,
-                    data={"alive": [bool(b) for b in self._last_membership]},
-                )
-        if ctl is not None and self.g > 1 and ctl.should_probe(state.step):
-            with jax.set_mesh(self.mesh):
-                if fr is not None:
-                    from repro.core.consensus import consensus_distance_masked_jit
-
-                    # membership mask, NOT the raw alive mask: a float drain
-                    # boost must not weight the draining node in the probe
-                    xi = consensus_distance_masked_jit(
-                        state.params,
-                        jnp.asarray(np.asarray(fr.alive) != 0, jnp.float32),
-                    )
-                elif self._folded_for_step == state.step:
-                    # folded probe: the last bucketed mixing step already
-                    # accumulated each bucket's Ξ² partial in its own
-                    # dispatch — only the final √mean runs, on the host
-                    from repro.core.buckets import xi_from_folded_sq
-
-                    xi = xi_from_folded_sq(self._folded_sq)
-                else:
-                    from repro.core.consensus import consensus_distance_jit
-
-                    xi = consensus_distance_jit(state.params)
             if tel.active:
-                tel.gauge("xi", float(xi), step=state.step)
-            ctl.observe(float(xi), state.step)
-        mix = (state.step + 1) % self.mix_every == 0
-        # Time-varying schedules advance per *gossip round*, not per raw
-        # step: with mix_every=H only every H-th step mixes, and indexing by
-        # raw step would alias a period-p family to the single phase
-        # H-1 mod p whenever p | H (e.g. one-peer n=16 with H=4 would gossip
-        # hop 8 forever, splitting the network into isolated pairs).
-        # the *selection* mask: for composed concurrent crashes it stays
-        # all-ones (base program + runtime masks), so the degraded-program
-        # branch — and any extra executable — is never taken
-        sel = fr.selection_mask() if fr is not None else None
-        palive = sel if sel is not None and not sel.all() else None
-        if self._bucketed and mix and not self.topology.centralized:
-            program = self._program_at(state.step // self.mix_every, epoch)
-            if program is not None and palive is not None:
-                program = program.degrade(palive)
-            if program is not None:
+                tel.event("rejoin", state.step, data={"node": int(node)})
+            with jax.set_mesh(self.mesh):
+                state = TrainState(
+                    adopt_neighbor_average(state.params, node, nbrs),
+                    adopt_neighbor_average(state.opt_state, node, nbrs),
+                    state.step,
+                )
+        for node in fr.depart:
+            # clean preemption departure: exact mean-preserving handoff
+            # to the neighborhood before the node's row goes dead
+            nbrs = rejoin_neighbors(
+                self.topology, fr, node, step=state.step, epoch=epoch,
+                mix_every=self.mix_every,
+            )
+            if tel.active:
+                tel.event("depart", state.step, data={"node": int(node)})
+            with jax.set_mesh(self.mesh):
+                state = TrainState(
+                    drain_handoff(state.params, node, nbrs, fr.alive),
+                    drain_handoff(state.opt_state, node, nbrs, fr.alive),
+                    state.step,
+                )
+        prev_membership = self._last_membership
+        self._last_membership = track_membership(
+            self._last_membership, fr, self.topology.controller, state.step
+        )
+        if (
+            tel.active
+            and prev_membership is not None
+            and self._last_membership != prev_membership
+        ):
+            tel.event(
+                "membership", state.step,
+                data={"alive": [bool(b) for b in self._last_membership]},
+            )
+        return fr, state
+
+    def _probe(self, state: TrainState, fr) -> None:
+        """Consensus probe: Ξ_t of the current state, fed to the controller
+        (a host sync on Ξ_t)."""
+        ctl = self.topology.controller
+        with jax.set_mesh(self.mesh):
+            if fr is not None:
+                from repro.core.consensus import consensus_distance_masked_jit
+
+                # membership mask, NOT the raw alive mask: a float drain
+                # boost must not weight the draining node in the probe
+                xi = consensus_distance_masked_jit(
+                    state.params,
+                    jnp.asarray(np.asarray(fr.alive) != 0, jnp.float32),
+                )
+            elif self._folded_for_step == state.step:
+                # folded probe: the last bucketed mixing step already
+                # accumulated each bucket's Ξ² partial in its own
+                # dispatch — only the final √mean runs, on the host
+                from repro.core.buckets import xi_from_folded_sq
+
+                xi = xi_from_folded_sq(self._folded_sq)
+            else:
+                from repro.core.consensus import consensus_distance_jit
+
+                xi = consensus_distance_jit(state.params)
+        if self.telemetry.active:
+            self.telemetry.gauge("xi", float(xi), step=state.step)
+        ctl.observe(float(xi), state.step)
+
+    def _bucketed_warm(self, program, has_m: bool, faulty: bool) -> bool:
+        """True when every executable of a bucketed step is already built."""
+        layout = self._bucket_layout
+        return layout is not None and all(
+            ("__bucket__", program.cache_key, w, has_m, faulty) in self._step_cache
+            for w in set(layout.widths)
+        )
+
+    def train_step(self, state: TrainState, batch: PyTree, lr: float, *, epoch: int = 0):
+        """One training step, inside the host span ``repro.train_step``
+        (``step_num`` = the step) and its phases ``repro.step.faults``,
+        ``repro.step.probe`` and ``repro.step.compile`` or
+        ``repro.step.dispatch`` (``repro.telemetry.profile``)."""
+        with profile.step_span(state.step):
+            t_start = self.telemetry.round_start()
+            ctl = self.topology.controller
+            fr = None
+            if self.fault_model is not None and self.g > 1:
+                with profile.span("step.faults"):
+                    fr, state = self._realize_faults(state, epoch)
+            if ctl is not None and self.g > 1 and ctl.should_probe(state.step):
+                with profile.span("step.probe"):
+                    self._probe(state, fr)
+            mix = (state.step + 1) % self.mix_every == 0
+            # Time-varying schedules advance per *gossip round*, not per raw
+            # step: with mix_every=H only every H-th step mixes, and indexing
+            # by raw step would alias a period-p family to the single phase
+            # H-1 mod p whenever p | H (e.g. one-peer n=16 with H=4 would
+            # gossip hop 8 forever, splitting the network into isolated pairs).
+            # the *selection* mask: for composed concurrent crashes it stays
+            # all-ones (base program + runtime masks), so the degraded-program
+            # branch — and any extra executable — is never taken
+            sel = fr.selection_mask() if fr is not None else None
+            palive = sel if sel is not None and not sel.all() else None
+            if self._bucketed and mix and not self.topology.centralized:
+                program = self._program_at(state.step // self.mix_every, epoch)
+                if program is not None and palive is not None:
+                    program = program.degrade(palive)
+                if program is not None:
+                    from repro.core.faults import realization_arrays
+
+                    self._bill_comm(program, state.params, state.step, fr)
+                    fault = realization_arrays(fr) if fr is not None else None
+                    warm = self._bucketed_warm(
+                        program, state.opt_state != (), fault is not None
+                    )
+                    with profile.span("step.dispatch" if warm else "step.compile"):
+                        p, o, loss, norms = self._bucketed_step(
+                            state, batch, lr, program, fault
+                        )
+                    self._finish_round(
+                        loss, norms, t_start, step=state.step, mix=True, lr=lr
+                    )
+                    return TrainState(p, o, state.step + 1), loss, norms
+            fn = self.step_fn(
+                epoch, step=state.step // self.mix_every,
+                mix=mix or self.topology.centralized,
+                program_alive=palive,
+            )
+            if mix and self.g > 1 and not self.topology.centralized:
+                self._bill_comm(self._last_program, state.params, state.step, fr)
+            args = (state.params, state.opt_state, batch, jnp.float32(lr))
+            if fr is not None:
                 from repro.core.faults import realization_arrays
 
-                self._bill_comm(program, state.params, state.step, fr)
-                fault = realization_arrays(fr) if fr is not None else None
-                p, o, loss, norms = self._bucketed_step(
-                    state, batch, lr, program, fault
-                )
-                self._finish_round(
-                    loss, norms, t_start, step=state.step, mix=True, lr=lr
-                )
-                return TrainState(p, o, state.step + 1), loss, norms
-        fn = self.step_fn(
-            epoch, step=state.step // self.mix_every,
-            mix=mix or self.topology.centralized,
-            program_alive=palive,
-        )
-        if mix and self.g > 1 and not self.topology.centralized:
-            self._bill_comm(self._last_program, state.params, state.step, fr)
-        args = (state.params, state.opt_state, batch, jnp.float32(lr))
-        if fr is not None:
-            from repro.core.faults import realization_arrays
-
-            args = args + (realization_arrays(fr),)
-        # a warm _LazyStep that has not built yet still traces legitimately
-        warm = self._was_warm and (
-            not isinstance(fn, _LazyStep) or fn._fn is not None
-        )
-        with jax.set_mesh(self.mesh), self._retrace_guard(
-            warm, f"spmd step {state.step}"
-        ):
-            p, o, loss, norms = fn(*args)
-        self._finish_round(loss, norms, t_start, step=state.step, mix=mix, lr=lr)
-        return TrainState(p, o, state.step + 1), loss, norms
+                args = args + (realization_arrays(fr),)
+            # a warm _LazyStep that has not built yet still traces legitimately
+            warm = self._was_warm and (
+                not isinstance(fn, _LazyStep) or fn._fn is not None
+            )
+            with jax.set_mesh(self.mesh), self._retrace_guard(
+                warm, f"spmd step {state.step}"
+            ), profile.span("step.dispatch" if warm else "step.compile"):
+                p, o, loss, norms = fn(*args)
+            self._finish_round(
+                loss, norms, t_start, step=state.step, mix=mix, lr=lr
+            )
+            return TrainState(p, o, state.step + 1), loss, norms
 
     # -- crash-consistent resume -------------------------------------------------
     def snapshot_extra(self) -> dict:
@@ -1207,6 +1268,30 @@ def build_config(arch: str, *, reduced: bool = False,
     if layers is not None:
         cfg = dataclasses.replace(cfg, n_layers=layers)
     return cfg
+
+
+def _start_profile(profile_dir: str) -> None:
+    """Start a profiler trace with the chip benchmark's options: host
+    annotations (the ``repro.*`` spans) without every Python call, and no
+    HLO protos (the step's text goes beside the trace instead)."""
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    options.enable_hlo_proto = False
+    jax.profiler.start_trace(profile_dir, profiler_options=options)
+
+
+def _stop_profile(profile_dir: str, trainer: SPMDTrainer) -> None:
+    """Stop the trace, then write each step executable's optimized HLO text
+    to ``<profile_dir>/step_hlo/<i>.txt``."""
+    from pathlib import Path
+
+    jax.profiler.stop_trace()
+    out = Path(profile_dir) / "step_hlo"
+    out.mkdir(parents=True, exist_ok=True)
+    for i, text in enumerate(trainer.step_hlo_texts().values()):
+        (out / f"{i}.txt").write_text(text)
+    print(f"profile: {profile_dir} (step HLO under {out})")
 
 
 def main(argv: Optional[list] = None) -> RunResult:
@@ -1342,7 +1427,27 @@ def main(argv: Optional[list] = None) -> RunResult:
                     help="gauge/variance emission cadence in steps "
                          "(with --telemetry; spans and counters are "
                          "per-step)")
+    ap.add_argument("--profile-dir", default="",
+                    help="write a JAX profiler trace of --profile-steps to "
+                         "this directory (TensorBoard/xprof), with the "
+                         "optimized HLO text of each step executable under "
+                         "step_hlo/, whose op_name metadata names the "
+                         "device scopes the trace's events lack")
+    ap.add_argument("--profile-steps", default="1:3",
+                    help="A:B, the steps A up to (not including) B that "
+                         "--profile-dir traces; the default skips step 0, "
+                         "which compiles")
     args = ap.parse_args(argv)
+    profile_steps = None
+    if args.profile_dir:
+        try:
+            profile_steps = tuple(int(x) for x in args.profile_steps.split(":"))
+        except ValueError:
+            profile_steps = ()
+        if len(profile_steps) != 2 or not 0 <= profile_steps[0] < profile_steps[1]:
+            raise SystemExit(
+                f"--profile-steps must be A:B with 0 <= A < B, got {args.profile_steps!r}"
+            )
 
     from repro.core.dsgd import make_topology
     from repro.data import SyntheticLM
@@ -1473,7 +1578,11 @@ def main(argv: Optional[list] = None) -> RunResult:
         base_batch=max(g * args.per_node_batch, 1), graph_degree=topo.degree_at(0),
     )
     losses, step_seconds = [], []
+    tracing = False
     for t in range(start_step, args.steps):
+        if profile_steps and t == profile_steps[0]:
+            _start_profile(args.profile_dir)
+            tracing = True
         batch = {k: jnp.asarray(v) for k, v in src.stacked(g, t, args.per_node_batch).items()}
         epoch = t // args.steps_per_epoch
         t0 = time.perf_counter()
@@ -1495,6 +1604,9 @@ def main(argv: Optional[list] = None) -> RunResult:
             trainer.telemetry.event(
                 "checkpoint_save", t + 1, data={"dir": args.ckpt_dir}
             )
+        if tracing and t + 1 in (profile_steps[1], args.steps):
+            _stop_profile(args.profile_dir, trainer)
+            tracing = False
     print(f"{len(step_seconds)} steps in {sum(step_seconds):.1f}s")
     if trainer.round_ms:
         ms = np.asarray(trainer.round_ms)

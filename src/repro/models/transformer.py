@@ -54,6 +54,7 @@ from repro.models.rwkv6 import (
     rwkv_block_decode,
     rwkv_block_defs,
 )
+from repro.telemetry import profile
 
 PyTree = Any
 
@@ -190,43 +191,45 @@ def apply_attn_block(
     Returns (h', cache_entry_or_None, aux_loss).
     """
     hn = _apply_norm(cfg, p["ln1"], h)
-    q, k, v = _qkv(p, cfg, hn)
-    sin, cos = rope(positions, cfg.head_dim, cfg.rope_theta)
-    q = apply_rope(q, sin, cos)
-    k = apply_rope(k, sin, cos)
-    out = attn_lib.multihead_attention(
-        q,
-        k,
-        v,
-        q_positions=positions,
-        k_positions=positions,
-        causal=True,
-        window=window,
-        impl=cfg.attn_impl,
-        chunk_size=cfg.attn_chunk,
-    )
-    mask = _pad_mask(cfg, p)
-    if mask is not None:
-        out = out * mask[None, None, :, None].astype(out.dtype)
-    h = h + jnp.einsum("bshk,hkd->bsd", out, p["wo"])
+    with profile.scope("attention"):
+        q, k, v = _qkv(p, cfg, hn)
+        sin, cos = rope(positions, cfg.head_dim, cfg.rope_theta)
+        q = apply_rope(q, sin, cos)
+        k = apply_rope(k, sin, cos)
+        out = attn_lib.multihead_attention(
+            q,
+            k,
+            v,
+            q_positions=positions,
+            k_positions=positions,
+            causal=True,
+            window=window,
+            impl=cfg.attn_impl,
+            chunk_size=cfg.attn_chunk,
+        )
+        mask = _pad_mask(cfg, p)
+        if mask is not None:
+            out = out * mask[None, None, :, None].astype(out.dtype)
+        h = h + jnp.einsum("bshk,hkd->bsd", out, p["wo"])
 
     aux = jnp.zeros((), jnp.float32)
     if "ffn" in p:
         hn2 = _apply_norm(cfg, p["ln2"], h)
-        if cfg.n_experts:
-            moe_fn = (
-                apply_moe_manual_ep if cfg.moe_impl == "manual_ep"
-                else partial(apply_moe, buf_constraint=cfg.moe_buf_constraint)
-            )
-            ff, aux = moe_fn(
-                p["ffn"],
-                hn2,
-                top_k=cfg.top_k,
-                capacity_factor=cfg.capacity_factor,
-            )
-        else:
-            ff = apply_mlp(p["ffn"], hn2, act=cfg.act)
-        h = h + ff
+        with profile.scope("mlp"):
+            if cfg.n_experts:
+                moe_fn = (
+                    apply_moe_manual_ep if cfg.moe_impl == "manual_ep"
+                    else partial(apply_moe, buf_constraint=cfg.moe_buf_constraint)
+                )
+                ff, aux = moe_fn(
+                    p["ffn"],
+                    hn2,
+                    top_k=cfg.top_k,
+                    capacity_factor=cfg.capacity_factor,
+                )
+            else:
+                ff = apply_mlp(p["ffn"], hn2, act=cfg.act)
+            h = h + ff
 
     cache_entry = (k, v, positions) if collect_cache else None
     return h, cache_entry, aux
@@ -318,6 +321,7 @@ def init_model(cfg: ArchConfig, key: jax.Array, tp_size: int = 16) -> PyTree:
 # Embedding / head / loss
 # ---------------------------------------------------------------------------
 
+@profile.scope("embed")
 def _embed(params, cfg: ArchConfig, tokens: jax.Array, patch_embeds=None):
     h = jnp.take(params["embed"], tokens, axis=0)
     if cfg.input_kind == "vlm" and patch_embeds is not None:
@@ -326,6 +330,7 @@ def _embed(params, cfg: ArchConfig, tokens: jax.Array, patch_embeds=None):
     return h
 
 
+@profile.scope("head")
 def _logits(params, cfg: ArchConfig, h: jax.Array) -> jax.Array:
     h = _apply_norm(cfg, params["final_norm"], h)
     return jnp.einsum("bsd,dv->bsv", h, params["head"])
@@ -462,7 +467,9 @@ def loss_fn(params, cfg: ArchConfig, batch: dict) -> jax.Array:
     )
     if cfg.input_kind == "vlm":
         logits = logits[:, cfg.n_patches :]
-    return cross_entropy(logits, batch["targets"]) + cfg.aux_loss_weight * aux
+    with profile.scope("head"):
+        ce = cross_entropy(logits, batch["targets"])
+    return ce + cfg.aux_loss_weight * aux
 
 
 # ---------------------------------------------------------------------------

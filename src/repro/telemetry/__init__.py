@@ -11,7 +11,11 @@ Typical wiring::
     sim = DecentralizedSimulator(..., telemetry=rec)
 
 Then ``python -m repro.telemetry summarize run.jsonl``.
+
+Spans and scopes that must share the device's clock go to the profiler
+through ``repro.telemetry.profile``.
 """
+from repro.telemetry import profile
 from repro.telemetry.recorder import (
     MetricsRecorder, coalesce_into, host_grad_norm,
 )
@@ -24,6 +28,7 @@ from repro.telemetry.summarize import (
 )
 
 __all__ = [
+    "profile",
     "MetricsRecorder",
     "JsonlSink",
     "MemorySink",

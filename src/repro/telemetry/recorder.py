@@ -211,24 +211,6 @@ class MetricsRecorder:
             rec["overrun"] = overrun
         self._emit(rec)
 
-    def bucket_span(self, t_start: Optional[float], *, step: int,
-                    index: int) -> None:
-        """Close a per-bucket ``bucket`` span: host *dispatch* wall-clock
-        (no extra blocking — a per-bucket sync would serialize exactly the
-        overlap the bucketed path exists to create)."""
-        if t_start is None:
-            return
-        ms = (time.perf_counter() - t_start) * 1e3
-        self._emit({"kind": "span", "step": int(step), "name": "bucket",
-                    "ms": ms, "index": int(index)})
-
-    def span_start(self) -> Optional[float]:
-        """Timestamp for a non-round span; None when sinks are off or span
-        timing was not requested."""
-        return (
-            time.perf_counter() if self.active and self.record_spans else None
-        )
-
     # -- events ----------------------------------------------------------------
     def event(self, name: str, step: int, *, data: Optional[dict] = None) -> None:
         self.event_count += 1

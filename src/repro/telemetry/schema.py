@@ -10,9 +10,8 @@ deliberately small — five kinds cover everything both engines observe:
              (``comm_bytes``, ``permutes``, ``program_applications``)
   gauge      point-in-time scalars (``loss``, ``xi``, ``lr``,
              ``grad_norm``)
-  span       measured wall-clock durations (``round`` per training step,
-             ``bucket`` per overlap-scheduled dispatch) with
-             deadline-overrun attribution on ``round`` spans
+  span       measured wall-clock durations (``round`` per training step)
+             with deadline-overrun attribution
   event      discrete occurrences: controller ``transition`` /
              ``controller`` (rearm/redensify reasons, same-step
              coalesced), membership changes (``join`` / ``rejoin`` /
@@ -76,8 +75,6 @@ _OPTIONAL = {
         "deadline_ms": _is_num,
         "overrun": lambda v: isinstance(v, bool),
         "mix": lambda v: isinstance(v, bool),
-        # bucket spans carry their dispatch index
-        "index": lambda v: isinstance(v, int) and v >= 0,
     },
     "event": {"data": lambda v: isinstance(v, dict)},
     "variance": {

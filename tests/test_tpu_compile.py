@@ -7,7 +7,9 @@ degrees 1 and 2: the stacked kernels over one 4096 x 14336 MLP matrix per
 node, the one-node ``gossip_update`` over a leaf that tiles in place and
 one with an odd vocab.  The HLO must hold the Mosaic ``tpu_custom_call``:
 the kernel was compiled, not interpreted, and its tile passed the VMEM
-budget check, which only compiled mode applies.  ``fused_apply_shard``,
+budget check, which only compiled mode applies.  It must also carry the
+kernel's stable name (``pallas_call(name=...)``), which a profiler trace
+names its events by.  ``fused_apply_shard``,
 the four-chip trainer's ``--fused-apply`` round, is compiled inside
 ``shard_map`` over the described 2x2 chips on the same two leaves.
 
@@ -73,6 +75,15 @@ def _compiled_hlo(fn, *shapes):
     return jax.jit(fn).lower(*shapes).compile().as_text()
 
 
+def _kernel_named(hlo, name):
+    """The compiled text holds a Mosaic custom call named ``name``."""
+    return any(
+        line.strip().removeprefix("ROOT ").startswith(f"%{name}.")
+        and "tpu_custom_call" in line
+        for line in hlo.splitlines()
+    )
+
+
 @pytest.mark.parametrize("leaf", LEAVES, ids=list(LEAVES))
 @pytest.mark.parametrize("deg", [1, 2])
 @pytest.mark.parametrize("dtype", DTYPES, ids=lambda d: jnp.dtype(d).name)
@@ -88,6 +99,7 @@ def test_gossip_update_compiles(one_chip, dtype, deg, leaf):
         s(shape, jnp.float32),
     )
     assert "tpu_custom_call" in hlo
+    assert _kernel_named(hlo, "gossip_leaf_update")
 
 
 @pytest.mark.parametrize("deg", [1, 2])
@@ -106,6 +118,7 @@ def test_gossip_program_update_compiles(one_chip, dtype, deg):
         s((N, deg + 1), jnp.float32), s((N, P)), s((N, P), jnp.float32),
     )
     assert "tpu_custom_call" in hlo
+    assert _kernel_named(hlo, "gossip_program_update")
 
 
 @pytest.mark.parametrize("deg", [1, 2])
@@ -122,6 +135,7 @@ def test_fused_bucket_update_compiles(one_chip, dtype, deg):
 
     hlo = _compiled_hlo(fn, s((N, P)), s((N, P)), s((N, P), jnp.float32))
     assert "tpu_custom_call" in hlo
+    assert _kernel_named(hlo, "gossip_program_update")
 
 
 @pytest.mark.parametrize("leaf", LEAVES, ids=list(LEAVES))
@@ -146,4 +160,5 @@ def test_fused_apply_shard_compiles(topo, deg, leaf):
     )
     hlo = _compiled_hlo(fn, s(jnp.bfloat16), s(jnp.bfloat16), s(jnp.float32))
     assert "tpu_custom_call" in hlo
+    assert _kernel_named(hlo, "gossip_leaf_update")
     assert "collective-permute" in hlo

@@ -69,3 +69,28 @@ def test_config_does_not_depend_on_backend(monkeypatch, backend):
 def test_mesh_larger_than_devices_names_the_fix():
     with pytest.raises(SystemExit, match="xla_force_host_platform_device_count=2"):
         main(ONE_NODE + ["--mesh", "2,1"])
+
+
+def test_profile_dir_traces_the_program_spans(tmp_path):
+    """``--profile-dir`` traces the steps it is given with the program's
+    host spans, and writes the step's HLO text, whose op_names carry the
+    device scopes."""
+    from jax.profiler import ProfileData
+
+    main(ONE_NODE + ["--steps", "2", "--profile-dir", str(tmp_path),
+                     "--profile-steps", "0:2"])
+    (xplane,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    host = {e.name for p in ProfileData.from_file(str(xplane)).planes
+            if p.name.startswith("/host:") for line in p.lines for e in line.events}
+    assert {"repro.train_step", "repro.step.compile", "repro.step.dispatch",
+            "repro.data.rows"} <= host
+    (text,) = [p.read_text() for p in (tmp_path / "step_hlo").glob("*.txt")]
+    for scope in ("model/jvp(", "model/transpose(jvp(", "rematted_computation",
+                  "/attention/", "/mlp/", "optimizer/", "norms/"):
+        assert scope in text, scope
+
+
+@pytest.mark.parametrize("steps", ["2", "2:1", "a:b", "-1:2"])
+def test_profile_steps_must_be_a_range(tmp_path, steps):
+    with pytest.raises(SystemExit, match="--profile-steps"):
+        main(ONE_NODE + ["--profile-dir", str(tmp_path), f"--profile-steps={steps}"])
