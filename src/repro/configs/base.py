@@ -44,7 +44,8 @@ class ArchConfig:
     input_kind: str = "tokens"   # tokens | vlm
     n_patches: int = 0
     # impl knobs
-    attn_impl: str = "reference"     # reference | chunked | chunked_skip
+    attn_impl: str = "auto"  # auto (splash on TPU where exact, else reference)
+    #   | reference | chunked | chunked_skip  (models/attention.resolve_impl)
     attn_chunk: int = 1024
     pad_heads: bool = False  # pad GQA groups so heads shard on the model axis
     #   (exact: padded heads are masked; see models/attention.head_padding)

@@ -35,6 +35,7 @@ bounded-executable-set invariant holds unchanged.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from collections import deque
@@ -311,12 +312,22 @@ class SPMDTrainer:
             alive=alive, link_up=link,
         )
 
+    @contextlib.contextmanager
+    def _call_span(self, warm: bool, step: int):
+        """The span ``repro.step.dispatch``, or ``repro.step.compile`` for a
+        call that builds an executable; bills to the recorder the calls its
+        trace added to ``profile.traced`` (attention blocks by path)."""
+        before = profile.traced()
+        with profile.span("step.dispatch" if warm else "step.compile"):
+            yield
+        for name, total in profile.traced().items():
+            if total > before[name]:
+                self.telemetry.counter(name, total - before[name], step=step)
+
     def _retrace_guard(self, warm: bool, label: str):
         """``debug_no_retrace`` guard around a warm cached-executable call
         (see ``DecentralizedSimulator._retrace_guard``)."""
         if not (self.debug_no_retrace and warm):
-            import contextlib
-
             return contextlib.nullcontext()
         from repro.analysis.recompile import assert_no_retrace
 
@@ -1115,7 +1126,7 @@ class SPMDTrainer:
                     warm = self._bucketed_warm(
                         program, state.opt_state != (), fault is not None
                     )
-                    with profile.span("step.dispatch" if warm else "step.compile"):
+                    with self._call_span(warm, state.step):
                         p, o, loss, norms = self._bucketed_step(
                             state, batch, lr, program, fault
                         )
@@ -1141,7 +1152,7 @@ class SPMDTrainer:
             )
             with jax.set_mesh(self.mesh), self._retrace_guard(
                 warm, f"spmd step {state.step}"
-            ), profile.span("step.dispatch" if warm else "step.compile"):
+            ), self._call_span(warm, state.step):
                 p, o, loss, norms = fn(*args)
             self._finish_round(
                 loss, norms, t_start, step=state.step, mix=mix, lr=lr
@@ -1608,6 +1619,11 @@ def main(argv: Optional[list] = None) -> RunResult:
             _stop_profile(args.profile_dir, trainer)
             tracing = False
     print(f"{len(step_seconds)} steps in {sum(step_seconds):.1f}s")
+    paths = trainer.telemetry.totals
+    print("attention blocks traced into the step, by path: " + ", ".join(
+        f"{name.removeprefix('attention.path.')} {paths.get(name, 0)}"
+        for name in profile.COUNTERS
+    ))
     if trainer.round_ms:
         ms = np.asarray(trainer.round_ms)
         line = (f"round trace: median {np.median(ms):.1f}ms "

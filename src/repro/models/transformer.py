@@ -185,9 +185,12 @@ def apply_attn_block(
     positions: jax.Array,
     window: Optional[int],
     collect_cache: bool,
+    impl: str,
 ):
     """Train/prefill attention block. h: (B, S, D); positions: (B, S).
 
+    ``impl``: the attention path, ``cfg.attn_impl`` as the caller resolved
+    it for these positions (``_attention_impl``).
     Returns (h', cache_entry_or_None, aux_loss).
     """
     hn = _apply_norm(cfg, p["ln1"], h)
@@ -204,7 +207,7 @@ def apply_attn_block(
             k_positions=positions,
             causal=True,
             window=window,
-            impl=cfg.attn_impl,
+            impl=impl,
             chunk_size=cfg.attn_chunk,
         )
         mask = _pad_mask(cfg, p)
@@ -359,6 +362,17 @@ def _maybe_remat(cfg, f):
     return jax.remat(f)
 
 
+def _attention_impl(cfg: ArchConfig, seq_len: int, window, n_calls: int) -> str:
+    """``cfg.attn_impl`` resolved for a forward over ``arange(seq_len)``,
+    counting its ``n_calls`` attention blocks under ``attention.path.<impl>``."""
+    impl = attn_lib.resolve_impl(
+        cfg.attn_impl, seq_len=seq_len, head_dim=cfg.head_dim,
+        window=window, aligned=True,
+    )
+    profile.count("attention.path." + impl, n_calls)
+    return impl
+
+
 def forward(
     params,
     cfg: ArchConfig,
@@ -393,10 +407,13 @@ def forward(
     if cfg.family == "hybrid":
         return _hybrid_forward(params, cfg, h, positions, window, collect_cache)
 
+    impl = _attention_impl(cfg, s, window, cfg.n_layers)
+
     def attn_apply(layer_p, hh):
         return apply_attn_block(
             layer_p, cfg, hh,
             positions=positions, window=window, collect_cache=collect_cache,
+            impl=impl,
         )
 
     def body(carry, layer_p):
@@ -416,6 +433,8 @@ def _hybrid_forward(params, cfg, h, positions, window, collect_cache):
     )
     shared = params["shared_attn"]
     aux0 = jnp.zeros((), jnp.float32)
+    n_groups = jax.tree.leaves(params["mamba_groups"])[0].shape[0]
+    impl = _attention_impl(cfg, h.shape[1], window, n_groups)
 
     def group_body(carry, group_p):
         hh, aux = carry
@@ -431,6 +450,7 @@ def _hybrid_forward(params, cfg, h, positions, window, collect_cache):
             lambda sp, hhh: apply_attn_block(
                 sp, cfg, hhh,
                 positions=positions, window=window, collect_cache=collect_cache,
+                impl=impl,
             ),
         )(shared, hh)
         m_states = jax.tree.map(lambda *xs: jnp.stack(xs), *m_states)

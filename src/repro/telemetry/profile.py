@@ -15,16 +15,24 @@ Two kinds, both seen by ``jax.profiler`` on one clock:
   instruction only, so a reduction joins them to the executable's text
   (``SPMDTrainer.step_hlo_texts``).
 
-Every name the program emits is listed in ``SPANS`` and ``SCOPES``; the
-README beside this file says which metric reads each one.  Nothing else in
-the program calls ``jax.profiler`` or ``jax.named_scope`` directly.
+A third kind is counted on the host as the program is traced, not run
+(``count``, ``traced``): how many calls of each kind one trace of a step
+puts in its executable, such as the attention path each block takes.  The
+trainer bills what a step's trace added to its ``MetricsRecorder``.
+
+Every name the program emits is listed in ``SPANS``, ``SCOPES`` and
+``COUNTERS``; the README beside this file says which metric reads each one.
+Nothing else in the program calls ``jax.profiler`` or ``jax.named_scope``
+directly.
 """
 from __future__ import annotations
 
+import collections
+
 import jax
 
-__all__ = ["SPANS", "SCOPES", "SPAN_PREFIX", "TRAIN_STEP",
-           "span", "step_span", "scope"]
+__all__ = ["SPANS", "SCOPES", "COUNTERS", "SPAN_PREFIX", "TRAIN_STEP",
+           "span", "step_span", "scope", "count", "traced"]
 
 SPAN_PREFIX = "repro."
 TRAIN_STEP = SPAN_PREFIX + "train_step"
@@ -53,6 +61,14 @@ SCOPES = (
     "probe",           # consensus distance
 )
 
+# trace-time counters: attention blocks by the path they resolved to
+COUNTERS = tuple(
+    "attention.path." + p
+    for p in ("splash", "reference", "chunked", "chunked_skip")
+)
+
+_traced: collections.Counter = collections.Counter()
+
 
 def span(name: str) -> jax.profiler.TraceAnnotation:
     """Host span ``repro.<name>`` (a name from ``SPANS``)."""
@@ -72,3 +88,15 @@ def scope(name: str):
     if name not in SCOPES:
         raise ValueError(f"unknown scope {name!r}; add it to SCOPES")
     return jax.named_scope(name)
+
+
+def count(name: str, n: int = 1) -> None:
+    """Add ``n`` calls to the trace-time counter ``name`` (from ``COUNTERS``)."""
+    if name not in COUNTERS:
+        raise ValueError(f"unknown counter {name!r}; add it to COUNTERS")
+    _traced[name] += n
+
+
+def traced() -> dict:
+    """Each trace-time counter's total over this process's traces."""
+    return {name: _traced[name] for name in COUNTERS}

@@ -12,6 +12,19 @@ def test_unknown_names_are_refused():
         profile.span("step.nothing")
     with pytest.raises(ValueError, match="SCOPES"):
         profile.scope("nothing")
+    with pytest.raises(ValueError, match="COUNTERS"):
+        profile.count("attention.path.nothing")
+
+
+def test_trace_time_counters_add_up():
+    before = profile.traced()
+    assert set(before) == set(profile.COUNTERS)
+    profile.count("attention.path.chunked", 3)
+    profile.count("attention.path.chunked")
+    after = profile.traced()
+    assert after["attention.path.chunked"] - before["attention.path.chunked"] == 4
+    assert all(after[n] == before[n] for n in profile.COUNTERS
+               if n != "attention.path.chunked")
 
 
 def test_spans_are_named_under_the_prefix():

@@ -11,7 +11,10 @@ budget check, which only compiled mode applies.  It must also carry the
 kernel's stable name (``pallas_call(name=...)``), which a profiler trace
 names its events by.  ``fused_apply_shard``,
 the four-chip trainer's ``--fused-apply`` round, is compiled inside
-``shard_map`` over the described 2x2 chips on the same two leaves.
+``shard_map`` over the described 2x2 chips on the same two leaves.  The
+gradient of one granite-8b attention block at the one-chip benchmark's
+shapes compiles through the path ``attn_impl="auto"`` resolves to on a
+mesh of one described chip: jax's splash kernel, forward and backward.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU compiler library, and every test worker
@@ -26,12 +29,16 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as PS
 
+from repro.configs import get_config
 from repro.core.graphs import Ring, one_peer_exponential
 from repro.core.schedule import compile_graph
 from repro.kernels.gossip_update import (
     fused_apply_shard, fused_bucket_update, gossip_program_update,
     gossip_update,
 )
+from repro.models import transformer as tfm
+from repro.models.attention import resolve_impl
+from repro.models.common import abstract_params
 
 N = 4
 P = 4096 * 14336  # one granite-8b MLP matrix per node
@@ -162,3 +169,38 @@ def test_fused_apply_shard_compiles(topo, deg, leaf):
     assert "tpu_custom_call" in hlo
     assert _kernel_named(hlo, "gossip_leaf_update")
     assert "collective-permute" in hlo
+
+
+def test_attention_block_grad_compiles_through_splash(topo):
+    """granite-8b (32/8 heads of 128), batch 1, S 4096, bfloat16, on a
+    1x1 mesh of one described v5e: the resolved path is the splash kernel,
+    whose forward (with the residuals the backward needs) and fused
+    backward are compiled Mosaic calls."""
+    cfg = get_config("granite-8b")
+    s = 4096
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    rep = NamedSharding(mesh, PS())
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
+        abstract_params(tfm.attn_block_defs(cfg, 1, with_ffn=False)),
+    )
+    h = jax.ShapeDtypeStruct((1, s, cfg.d_model), cfg.dtype, sharding=rep)
+    impls = []
+
+    def loss(p, h):
+        impl = resolve_impl(cfg.attn_impl, seq_len=s, head_dim=cfg.head_dim,
+                            aligned=True)
+        impls.append(impl)
+        positions = jnp.arange(s, dtype=jnp.int32)[None]
+        out, _, _ = tfm.apply_attn_block(
+            p, cfg, h, positions=positions, window=None,
+            collect_cache=False, impl=impl,
+        )
+        return out.astype(jnp.float32).sum()
+
+    # the trainer's matmul precision (this suite's conftest raises it)
+    with jax.set_mesh(mesh), jax.default_matmul_precision("default"):
+        hlo = _compiled_hlo(jax.grad(loss, argnums=(0, 1)), params, h)
+    assert impls == ["splash"]
+    assert _kernel_named(hlo, "splash_mha_fwd_residuals")
+    assert _kernel_named(hlo, "splash_mha_dkv_no_residuals")

@@ -43,6 +43,18 @@ def test_layers_flag_sets_depth_in_the_run(capsys):
     assert "granite-8b: 1 layers" in capsys.readouterr().out
 
 
+def test_summary_counts_attention_blocks_by_path(capsys):
+    """The step's one trace bills each layer's attention block to the path
+    it resolved to; on the CPU that is the reference, and later steps,
+    which reuse the executable, add nothing."""
+    res = main(ONE_NODE + ["--layers", "3"])
+    totals = res.trainer.telemetry.totals
+    assert totals["attention.path.reference"] == 3
+    assert "attention.path.splash" not in totals
+    assert ("attention blocks traced into the step, by path: splash 0, "
+            "reference 3, chunked 0, chunked_skip 0") in capsys.readouterr().out
+
+
 @pytest.mark.parametrize("reduced", [False, True])
 @pytest.mark.parametrize("layers", [1, 4])
 def test_layers_changes_only_n_layers(reduced, layers):
