@@ -316,7 +316,8 @@ class SPMDTrainer:
     def _call_span(self, warm: bool, step: int):
         """The span ``repro.step.dispatch``, or ``repro.step.compile`` for a
         call that builds an executable; bills to the recorder the calls its
-        trace added to ``profile.traced`` (attention blocks by path)."""
+        trace added to ``profile.traced`` (attention blocks by path, chunked
+        WKV blocks)."""
         before = profile.traced()
         with profile.span("step.dispatch" if warm else "step.compile"):
             yield
@@ -1622,8 +1623,9 @@ def main(argv: Optional[list] = None) -> RunResult:
     paths = trainer.telemetry.totals
     print("attention blocks traced into the step, by path: " + ", ".join(
         f"{name.removeprefix('attention.path.')} {paths.get(name, 0)}"
-        for name in profile.COUNTERS
+        for name in profile.COUNTERS if name.startswith("attention.path.")
     ))
+    print(f"WKV blocks traced into the step, chunked: {paths.get('wkv.chunked', 0)}")
     if trainer.round_ms:
         ms = np.asarray(trainer.round_ms)
         line = (f"round trace: median {np.median(ms):.1f}ms "

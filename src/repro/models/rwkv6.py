@@ -2,10 +2,16 @@
 
 Faithful structure: LayerNormed sublayers, token-shift lerps, LoRA-modulated
 data-dependent decay ``w_t = exp(-exp(w0 + lora_w(x̄_t)))``, bonus ``u``,
-per-head group norm, SiLU-gated output, squared-ReLU channel mix.  (The full
-Finch also LoRA-modulates the token-shift lerp coefficients; we keep static
-lerp coefficients there — noted in DESIGN.md — while the decay, Finch's
-headline data-dependence, is fully dynamic.)
+per-head group norm, SiLU-gated output, squared-ReLU channel mix.  One
+departure: the full Finch also LoRA-modulates the token-shift lerp
+coefficients (ddlerp); here they are static.  That LoRA adds parameter
+leaves, and the chip benchmark's plain reference (``bench/reference/``)
+pins today's parameter tree, so it waits for the benchmark to move with
+it; the decay, Finch's headline data-dependence, is fully dynamic.
+
+Device scopes (``repro.telemetry.profile``): the time mix runs under
+``time_mix``, its WKV recurrence under ``time_mix/wkv``, and the channel
+mix, the block's feed-forward, under ``mlp``.
 
 State per layer: (wkv (B, H, N, N), previous *normed* token for each of the
 two token-shifted sublayers).
@@ -26,10 +32,17 @@ from repro.models.common import (
     zeros_init,
 )
 from repro.models.recurrence import rwkv_chunked, rwkv_step
+from repro.telemetry import profile
 
 __all__ = ["rwkv_block_defs", "apply_rwkv_block", "rwkv_block_decode", "RWKVState"]
 
 _LORA_RANK = 64
+# The per-head norm's eps, as the published RWKV-6 code sets it: 1e-5 times
+# the square of its head_size_divisor, 8.  The norm divides each head's WKV
+# output by its spread, and the first token's output is the bonus term
+# alone, a scalar times v: where that scalar is near zero, the eps bounds
+# how steeply the gradient depends on it.
+GROUP_NORM_EPS = 64e-5
 
 
 class RWKVState(NamedTuple):
@@ -94,7 +107,7 @@ def _decay_logw(tm, xw: jax.Array) -> jax.Array:
     return -jnp.exp(jnp.clip(tm["decay_w0"] + lora, -8.0, 6.0))
 
 
-def _group_norm(x: jax.Array, n_heads: int, g, b, eps=1e-5) -> jax.Array:
+def _group_norm(x: jax.Array, n_heads: int, g, b, eps=GROUP_NORM_EPS) -> jax.Array:
     """Per-head LayerNorm of (B, S, D)."""
     bsz, s, d = x.shape
     xh = x.reshape(bsz, s, n_heads, d // n_heads).astype(jnp.float32)
@@ -133,15 +146,18 @@ def apply_rwkv_block(
     tm, cm = params["time_mix"], params["channel_mix"]
 
     xn = layer_norm(x, params["ln1_g"], params["ln1_b"])
-    shifted = _shift(xn, state.shift_tm)
-    r, k, v, g, logw = _time_mix_inputs(tm, xn, shifted, n_heads)
-    o, wkv = rwkv_chunked(r, k, v, logw, tm["bonus_u"], state.wkv, chunk=chunk)
-    o = _group_norm(o.reshape(b, s, d), n_heads, tm["gn_g"], tm["gn_b"])
-    h = x + (o * g) @ tm["w_o"]
+    with profile.scope("time_mix"):
+        shifted = _shift(xn, state.shift_tm)
+        r, k, v, g, logw = _time_mix_inputs(tm, xn, shifted, n_heads)
+        with profile.scope("wkv"):
+            o, wkv = rwkv_chunked(r, k, v, logw, tm["bonus_u"], state.wkv, chunk=chunk)
+        o = _group_norm(o.reshape(b, s, d), n_heads, tm["gn_g"], tm["gn_b"])
+        h = x + (o * g) @ tm["w_o"]
 
     hn = layer_norm(h, params["ln2_g"], params["ln2_b"])
-    shifted_c = _shift(hn, state.shift_cm)
-    out = h + _channel_mix(cm, hn, shifted_c)
+    with profile.scope("mlp"):
+        shifted_c = _shift(hn, state.shift_cm)
+        out = h + _channel_mix(cm, hn, shifted_c)
 
     return out, RWKVState(wkv=wkv, shift_tm=xn[:, -1], shift_cm=hn[:, -1])
 

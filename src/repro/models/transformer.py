@@ -393,6 +393,7 @@ def forward(
 
     if cfg.family == "ssm":
         n_heads = cfg.n_heads or cfg.d_model // 64
+        profile.count("wkv.chunked", cfg.n_layers)
 
         def body(carry, layer_p):
             st0 = RWKVState.empty(b, n_heads, cfg.d_model // n_heads, cfg.d_model, h.dtype)

@@ -17,7 +17,8 @@ Two kinds, both seen by ``jax.profiler`` on one clock:
 
 A third kind is counted on the host as the program is traced, not run
 (``count``, ``traced``): how many calls of each kind one trace of a step
-puts in its executable, such as the attention path each block takes.  The
+puts in its executable, such as the attention path each block takes or
+the RWKV-6 blocks whose WKV runs the chunked scan.  The
 trainer bills what a step's trace added to its ``MetricsRecorder``.
 
 Every name the program emits is listed in ``SPANS``, ``SCOPES`` and
@@ -52,7 +53,9 @@ SCOPES = (
     "model",           # loss and gradient: JAX marks forward, backward, recompute
     "embed",           # token embedding
     "attention",       # QKV, RoPE, scores, mask, softmax, weighted sum, output
-    "mlp",             # feed-forward (dense or mixture of experts)
+    "time_mix",        # RWKV-6 time mix: lerps, projections, decay, WKV, norm, output
+    "wkv",             # the chunked WKV recurrence, inside time_mix
+    "mlp",             # feed-forward (dense, mixture of experts, RWKV-6 channel mix)
     "head",            # final norm, logits, cross entropy
     "optimizer",       # the local optimizer update
     "gossip",          # a mixing program's apply
@@ -61,11 +64,12 @@ SCOPES = (
     "probe",           # consensus distance
 )
 
-# trace-time counters: attention blocks by the path they resolved to
+# trace-time counters: attention blocks by the path they resolved to, and
+# RWKV-6 blocks whose WKV runs the chunked scan
 COUNTERS = tuple(
     "attention.path." + p
     for p in ("splash", "reference", "chunked", "chunked_skip")
-)
+) + ("wkv.chunked",)
 
 _traced: collections.Counter = collections.Counter()
 

@@ -55,6 +55,21 @@ def test_summary_counts_attention_blocks_by_path(capsys):
             "reference 3, chunked 0, chunked_skip 0") in capsys.readouterr().out
 
 
+def test_summary_counts_chunked_wkv_blocks(capsys):
+    """An RWKV-6 step's one trace bills each layer's block to the chunked
+    WKV scan and no block to an attention path."""
+    argv = list(ONE_NODE)
+    argv[argv.index("granite-8b")] = "rwkv6-1.6b"
+    res = main(argv + ["--layers", "2"])
+    totals = res.trainer.telemetry.totals
+    assert totals["wkv.chunked"] == 2
+    assert not any(k.startswith("attention.path.") for k in totals)
+    out = capsys.readouterr().out
+    assert "WKV blocks traced into the step, chunked: 2" in out
+    assert ("attention blocks traced into the step, by path: splash 0, "
+            "reference 0, chunked 0, chunked_skip 0") in out
+
+
 @pytest.mark.parametrize("reduced", [False, True])
 @pytest.mark.parametrize("layers", [1, 4])
 def test_layers_changes_only_n_layers(reduced, layers):
