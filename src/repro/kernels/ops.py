@@ -12,12 +12,14 @@ import jax.numpy as jnp
 from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.gossip_update import gossip_update as _gossip
 from repro.kernels.stats import l2_norms as _l2
+from repro.kernels.wkv import wkv as _wkv
 
 __all__ = [
     "flash_attention",
     "gossip_update",
     "gossip_program_update",
     "l2_norms",
+    "wkv",
     "default_interpret",
 ]
 
@@ -65,3 +67,11 @@ def gossip_program_update(theta, neighbors, weights, grad, momentum, *, lr,
 def l2_norms(x, *, block=2048, interpret=None):
     itp = default_interpret() if interpret is None else interpret
     return _l2(x, block=block, interpret=itp)
+
+
+def wkv(r, k, v, logw, u, s0, *, interpret=None):
+    """RWKV-6's WKV recurrence, forward and backward kernels bound by a
+    ``custom_vjp``: r/k/v/logw (B, L, H, N), u (H, N), s0 (B, H, N, N) ->
+    (o (B, L, H, N), final state float32), as ``ref.wkv_ref``."""
+    itp = default_interpret() if interpret is None else interpret
+    return _wkv(r, k, v, logw, u, s0, interpret=itp)

@@ -6,7 +6,10 @@ import math
 import jax
 import jax.numpy as jnp
 
-__all__ = ["flash_attention_ref", "gossip_update_ref", "l2_norms_ref"]
+# the WKV kernel's oracle: the step-by-step scan of RWKV-6's recurrence
+from repro.models.recurrence import rwkv_scan_reference as wkv_ref
+
+__all__ = ["flash_attention_ref", "gossip_update_ref", "l2_norms_ref", "wkv_ref"]
 
 
 def flash_attention_ref(

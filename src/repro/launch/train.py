@@ -1625,7 +1625,8 @@ def main(argv: Optional[list] = None) -> RunResult:
         f"{name.removeprefix('attention.path.')} {paths.get(name, 0)}"
         for name in profile.COUNTERS if name.startswith("attention.path.")
     ))
-    print(f"WKV blocks traced into the step, chunked: {paths.get('wkv.chunked', 0)}")
+    print(f"WKV blocks traced into the step, chunked: {paths.get('wkv.chunked', 0)}, "
+          f"pallas: {paths.get('wkv.pallas', 0)}")
     if trainer.round_ms:
         ms = np.asarray(trainer.round_ms)
         line = (f"round trace: median {np.median(ms):.1f}ms "

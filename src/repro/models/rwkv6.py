@@ -13,16 +13,22 @@ Device scopes (``repro.telemetry.profile``): the time mix runs under
 ``time_mix``, its WKV recurrence under ``time_mix/wkv``, and the channel
 mix, the block's feed-forward, under ``mlp``.
 
+The WKV recurrence runs on one of two paths (``resolve_wkv``): the Pallas
+kernel pair of ``repro.kernels.wkv`` on a TPU whose mesh leaves no axis to
+the partitioner, else XLA's chunk scan, ``models/recurrence.rwkv_chunked``.
+
 State per layer: (wkv (B, H, N, N), previous *normed* token for each of the
 two token-shifted sublayers).
 """
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.ops import wkv as wkv_kernel
+from repro.models.attention import _auto_partitioned, _mesh_platform
 from repro.models.common import (
     ParamDef,
     he_normal,
@@ -34,7 +40,8 @@ from repro.models.common import (
 from repro.models.recurrence import rwkv_chunked, rwkv_step
 from repro.telemetry import profile
 
-__all__ = ["rwkv_block_defs", "apply_rwkv_block", "rwkv_block_decode", "RWKVState"]
+__all__ = ["rwkv_block_defs", "apply_rwkv_block", "rwkv_block_decode", "RWKVState",
+           "resolve_wkv"]
 
 _LORA_RANK = 64
 # The per-head norm's eps, as the published RWKV-6 code sets it: 1e-5 times
@@ -138,10 +145,25 @@ def _channel_mix(cm, xn, shifted):
     return jax.nn.sigmoid(xr @ cm["w_r"]) * (kk @ cm["w_v"])
 
 
+def resolve_wkv(mesh=None, platform: Optional[str] = None) -> str:
+    """The WKV path: ``"pallas"`` on a TPU where no mesh axis of size > 1 is
+    left to the partitioner (which cannot split a Pallas call), else
+    ``"chunked"``.  ``mesh`` defaults to the ambient abstract mesh and
+    ``platform`` to its devices'."""
+    if mesh is None:
+        mesh = jax.sharding.get_abstract_mesh()
+    on_chip = (platform or _mesh_platform(mesh)) == "tpu"
+    return "pallas" if on_chip and not _auto_partitioned(mesh) else "chunked"
+
+
 def apply_rwkv_block(
-    params, x: jax.Array, state: RWKVState, *, n_heads: int, chunk: int = 32
+    params, x: jax.Array, state: RWKVState, *, n_heads: int, chunk: int = 32,
+    wkv_impl: str = "chunked",
 ) -> tuple[jax.Array, RWKVState]:
-    """Full block (time mix + channel mix, own norms/residuals). x: (B, S, D)."""
+    """Full block (time mix + channel mix, own norms/residuals). x: (B, S, D).
+    ``wkv_impl``: ``"chunked"`` (XLA's scan over chunks of ``chunk``) or
+    ``"pallas"`` (``kernels/wkv.py`` compiled for a TPU, which sizes its
+    own chunks)."""
     b, s, d = x.shape
     tm, cm = params["time_mix"], params["channel_mix"]
 
@@ -150,7 +172,12 @@ def apply_rwkv_block(
         shifted = _shift(xn, state.shift_tm)
         r, k, v, g, logw = _time_mix_inputs(tm, xn, shifted, n_heads)
         with profile.scope("wkv"):
-            o, wkv = rwkv_chunked(r, k, v, logw, tm["bonus_u"], state.wkv, chunk=chunk)
+            if wkv_impl == "pallas":
+                o, wkv = wkv_kernel(r, k, v, logw, tm["bonus_u"], state.wkv,
+                                    interpret=False)
+            else:
+                o, wkv = rwkv_chunked(r, k, v, logw, tm["bonus_u"], state.wkv,
+                                      chunk=chunk)
         o = _group_norm(o.reshape(b, s, d), n_heads, tm["gn_g"], tm["gn_b"])
         h = x + (o * g) @ tm["w_o"]
 

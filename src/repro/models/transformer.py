@@ -51,6 +51,7 @@ from repro.models.moe import apply_moe, apply_moe_manual_ep, moe_defs
 from repro.models.rwkv6 import (
     RWKVState,
     apply_rwkv_block,
+    resolve_wkv,
     rwkv_block_decode,
     rwkv_block_defs,
 )
@@ -393,12 +394,14 @@ def forward(
 
     if cfg.family == "ssm":
         n_heads = cfg.n_heads or cfg.d_model // 64
-        profile.count("wkv.chunked", cfg.n_layers)
+        wkv_impl = resolve_wkv()
+        profile.count("wkv." + wkv_impl, cfg.n_layers)
 
         def body(carry, layer_p):
             st0 = RWKVState.empty(b, n_heads, cfg.d_model // n_heads, cfg.d_model, h.dtype)
             out, st = _maybe_remat(cfg, partial(
-                apply_rwkv_block, n_heads=n_heads, chunk=cfg.rec_chunk
+                apply_rwkv_block, n_heads=n_heads, chunk=cfg.rec_chunk,
+                wkv_impl=wkv_impl,
             ))(layer_p, carry, st0)
             return out, st
 

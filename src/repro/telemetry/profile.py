@@ -18,7 +18,7 @@ Two kinds, both seen by ``jax.profiler`` on one clock:
 A third kind is counted on the host as the program is traced, not run
 (``count``, ``traced``): how many calls of each kind one trace of a step
 puts in its executable, such as the attention path each block takes or
-the RWKV-6 blocks whose WKV runs the chunked scan.  The
+the WKV path (Pallas kernel or chunk scan) of each RWKV-6 block.  The
 trainer bills what a step's trace added to its ``MetricsRecorder``.
 
 Every name the program emits is listed in ``SPANS``, ``SCOPES`` and
@@ -54,7 +54,7 @@ SCOPES = (
     "embed",           # token embedding
     "attention",       # QKV, RoPE, scores, mask, softmax, weighted sum, output
     "time_mix",        # RWKV-6 time mix: lerps, projections, decay, WKV, norm, output
-    "wkv",             # the chunked WKV recurrence, inside time_mix
+    "wkv",             # the WKV recurrence (kernel or chunk scan), inside time_mix
     "mlp",             # feed-forward (dense, mixture of experts, RWKV-6 channel mix)
     "head",            # final norm, logits, cross entropy
     "optimizer",       # the local optimizer update
@@ -65,11 +65,11 @@ SCOPES = (
 )
 
 # trace-time counters: attention blocks by the path they resolved to, and
-# RWKV-6 blocks whose WKV runs the chunked scan
+# RWKV-6 blocks by the WKV path: the Pallas kernel or XLA's chunk scan
 COUNTERS = tuple(
     "attention.path." + p
     for p in ("splash", "reference", "chunked", "chunked_skip")
-) + ("wkv.chunked",)
+) + ("wkv.pallas", "wkv.chunked")
 
 _traced: collections.Counter = collections.Counter()
 

@@ -1,8 +1,9 @@
 """The program's RWKV-6 against the chip benchmark's plain float32
 reference (``bench/reference/rwkv6.py``) on seeded random weights, at a
 size the CPU runs in seconds: the loss and the first gradient through
-``transformer.loss_fn``, the path ``SPMDTrainer`` trains, and the chunked
-WKV recurrence against the step-by-step scan."""
+``transformer.loss_fn``, the path ``SPMDTrainer`` trains, and both paths of
+the WKV recurrence, XLA's chunk scan and the Pallas kernel, against the
+step-by-step scan."""
 from __future__ import annotations
 
 import dataclasses
@@ -116,16 +117,49 @@ def test_float8_control_is_outside_the_bound(reference):
     assert _gap(norms, reference[seed][1]) > 2 * BF16_BOUND
 
 
-@pytest.mark.parametrize("seq, chunk", [(64, 16), (60, 16), (50, 64), (96, 32)],
-                         ids=["divides", "remainder", "one_short_chunk", "three"])
-def test_chunked_wkv_matches_the_scan_with_gradients(seq, chunk):
+PUBLISHED_DECAY = (-6.0, 1.0)
+CLIP_DECAY = (-8.0, 6.0)  # the program's clip: log decays -e^6 to -e^-8
+# (seq, chunk of the XLA scan, batch, log decay exponent range, id): the
+# published initialisation's decay range, then the whole range of the
+# program's clip, a sequence that leaves the kernel's 64-row chunk a
+# remainder, and a batch of two
+WKV_CASES = [
+    (64, 16, 1, PUBLISHED_DECAY, "divides"),
+    (60, 16, 1, PUBLISHED_DECAY, "remainder"),
+    (50, 64, 1, PUBLISHED_DECAY, "one_short_chunk"),
+    (96, 32, 1, PUBLISHED_DECAY, "three"),
+    (64, 64, 1, CLIP_DECAY, "clip_range"),
+    (100, 32, 1, PUBLISHED_DECAY, "two_chunks_remainder"),
+    (48, 16, 2, PUBLISHED_DECAY, "batch2"),
+]
+# Over the clip's range a chunk's cumulative log decay reaches 64 x e^6,
+# about 26,000, where a float32 step is 2e-3: both chunked paths take
+# differences of such sums, and read 1.2e-4 of the largest magnitude
+# against the scan (CPU, both at chunk 64).  That case is held to 5e-4 of
+# the largest magnitude on every number.
+CLIP_TOL = 5e-4
+
+
+def _wkv_cases():
+    """Every case on the XLA chunk scan under its own id, and on the Pallas
+    kernel (interpret mode) under the id with ``-pallas``."""
+    for seq, chunk, b, decay, name in WKV_CASES:
+        yield pytest.param(seq, chunk, b, decay, "chunked", id=name)
+        yield pytest.param(seq, chunk, b, decay, "pallas", id=name + "-pallas")
+
+
+@pytest.mark.parametrize("seq, chunk, b, decay, impl", _wkv_cases())
+def test_chunked_wkv_matches_the_scan_with_gradients(seq, chunk, b, decay, impl):
     """Output, final state and the gradients of both with respect to every
-    input, at the published widths' head size and the published
-    initialisation's decay range."""
-    b, h, n = 1, 2, 64
+    input, at the published widths' head size, against the step-by-step
+    scan; every output and gradient is finite."""
+    from repro.kernels import ops
+
+    h, n = 2, 64
     ks = jax.random.split(jax.random.PRNGKey(seq * 100 + chunk), 6)
     r, k, v = (jax.random.normal(ks[i], (b, seq, h, n)) for i in range(3))
-    logw = -jnp.exp(jax.random.uniform(ks[3], (b, seq, h, n), minval=-6.0, maxval=1.0))
+    logw = -jnp.exp(jax.random.uniform(ks[3], (b, seq, h, n),
+                                       minval=decay[0], maxval=decay[1]))
     u = jax.random.normal(ks[4], (h, n)) * 0.3
     s0 = jax.random.normal(ks[5], (b, h, n, n)) * 0.1
     cot = jax.random.normal(jax.random.PRNGKey(1), (b, seq, h, n))
@@ -136,13 +170,24 @@ def test_chunked_wkv_matches_the_scan_with_gradients(seq, chunk):
             return jnp.sum(o * cot) + jnp.sum(s)
         return g
 
-    chunked = lambda *a: rwkv_chunked(*a, chunk=chunk)
+    if impl == "pallas":
+        chunked = lambda *a: ops.wkv(*a, interpret=True)
+    else:
+        chunked = lambda *a: rwkv_chunked(*a, chunk=chunk)
     args = (r, k, v, logw, u, s0)
     with jax.default_matmul_precision("highest"):
         o1, s1 = chunked(*args)
         o2, s2 = rwkv_scan_reference(*args)
         g1 = jax.grad(scalar(chunked), argnums=tuple(range(6)))(*args)
         g2 = jax.grad(scalar(rwkv_scan_reference), argnums=tuple(range(6)))(*args)
+    for x in (o1, s1, *g1):
+        assert np.all(np.isfinite(np.asarray(x)))
+    if decay == CLIP_DECAY:
+        for a, b_ in zip((o1, s1, *g1), (o2, s2, *g2)):
+            scale = float(jnp.max(jnp.abs(b_)))
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b_), rtol=0,
+                                       atol=CLIP_TOL * scale)
+        return
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(s1), np.asarray(s2), rtol=1e-4, atol=1e-4)
     for a, b_ in zip(g1, g2):

@@ -204,3 +204,41 @@ def test_attention_block_grad_compiles_through_splash(topo):
     assert impls == ["splash"]
     assert _kernel_named(hlo, "splash_mha_fwd_residuals")
     assert _kernel_named(hlo, "splash_mha_dkv_no_residuals")
+
+
+def test_rwkv_block_grad_compiles_through_the_wkv_kernel(topo):
+    """One RWKV-6 Finch 1.6B block (d_model 2048, 32 heads of 64, channel
+    mix 7168), batch 1, S 4096, bfloat16, on a 1x1 mesh of one described
+    v5e: the WKV path resolves to the Pallas kernel, and its forward (with
+    the per-chunk states the backward reads) and backward are compiled
+    Mosaic calls that carry the ``wkv`` scope."""
+    from repro.models.rwkv6 import (RWKVState, apply_rwkv_block, resolve_wkv,
+                                    rwkv_block_defs)
+
+    d, heads, s = 2048, 32, 4096
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    rep = NamedSharding(mesh, PS())
+    shape = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep)
+    params = jax.tree.map(
+        shape, abstract_params(rwkv_block_defs(d, heads, 7168, jnp.bfloat16)))
+    x = jax.ShapeDtypeStruct((1, s, d), jnp.bfloat16, sharding=rep)
+    state = jax.tree.map(shape, jax.eval_shape(
+        lambda: RWKVState.empty(1, heads, d // heads, d, jnp.bfloat16)))
+    impls = []
+
+    def loss(p, x, st):
+        impl = resolve_wkv()
+        impls.append(impl)
+        out, _ = apply_rwkv_block(p, x, st, n_heads=heads, wkv_impl=impl)
+        return out.astype(jnp.float32).sum()
+
+    with jax.set_mesh(mesh):
+        hlo = _compiled_hlo(jax.value_and_grad(loss, argnums=(0, 1)), params, x, state)
+    assert impls == ["pallas"]
+    calls = [line for line in hlo.splitlines()
+             if "tpu_custom_call" in line and " = " in line]
+    for kernel in ("wkv_fwd_states", "wkv_bwd"):
+        mine = [line for line in calls
+                if kernel in line.split(" = ", 1)[0]]
+        assert mine, kernel
+        assert all("/wkv/" in line.split("op_name=", 1)[-1] for line in mine), kernel
