@@ -111,14 +111,14 @@ def used_program_keys(step_cache) -> set:
     """Program cache keys behind an engine ``_step_cache``'s entries.
 
     Strips the engines' wrappers — ``(key, "faulty")`` fault signatures,
-    ``("__bucket__", key, width, has_m, faulty)`` bucket executables — and
-    drops ``__``-prefixed internal executables (grads, split/merge,
-    centralized/local closures) plus the SPMD trainer's ``None``
-    programless key.
+    and the simulator's ``("__bucket__", key, width, has_m, faulty)``
+    bucket executables (the trainer does not bucket) — and drops
+    ``__``-prefixed internal executables (grads, centralized/local
+    closures) plus the SPMD trainer's ``None`` programless key.
     """
     used = set()
     for k in step_cache:
-        if k is None or isinstance(k, str):
+        if k is None:
             continue
         if isinstance(k, tuple) and len(k) == 2 and k[1] == "faulty":
             k = k[0]

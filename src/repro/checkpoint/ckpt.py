@@ -104,7 +104,7 @@ def load_checkpoint(directory: str, template: PyTree, step: int | None = None) -
 
 
 def validate_run_config(
-    recorded: dict, *, topology: str, bucket_mb: float | None,
+    recorded: dict, *, topology: str, bucket_mb: float | None = None,
     n: int | None = None, n_label: str = "node count",
 ) -> None:
     """Fail-fast resume: compare a checkpoint's recorded ``run_config``

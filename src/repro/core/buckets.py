@@ -12,10 +12,9 @@ This module supplies the two pieces that break that tail barrier
     pytree into contiguous buckets of ~``bucket_mb`` MiB (float32
     accounting, so the layout is dtype- and value-independent).  Buckets
     may cross leaf boundaries; a segment table maps each bucket to its
-    ``(leaf, start, stop)`` slices, and ``split_*`` / ``merge_*`` views
-    round-trip exactly.  Both engines (the vmap simulator and the SPMD
-    trainer's stacked realization) build the SAME layout from abstract
-    leaf shapes, so a checkpoint moved between engines buckets identically.
+    ``(leaf, start, stop)`` slices, and ``split_stacked`` /
+    ``merge_stacked`` round-trip exactly.  The layout is built from
+    abstract leaf shapes, so the same tree always buckets identically.
 
 ``build_bucket_step``
     The per-bucket executor: one jitted dispatch that runs bucket *b*'s
@@ -23,7 +22,7 @@ This module supplies the two pieces that break that tail barrier
     Pallas kernel), plus this bucket's partial Ξ_t sum, accumulated into
     a tiny (n,) token threaded bucket-to-bucket.  Bucket *i*'s (n, w)
     parameter/gradient payload carries NO dependency on bucket *i−1*'s
-    output — only the token does — so the engines issue all B dispatches
+    output — only the token does — so the simulator issues all B dispatches
     back-to-back, the token pins a consistent cross-device execution
     order (required: independent collective-bearing executables can
     otherwise start in different per-device orders and deadlock at the
@@ -43,7 +42,7 @@ programs, not with buckets × faults.
 The Ξ_t probe fold: each bucket's dispatch returns the per-node partial
 sum  Σ_{c ∈ bucket} (x_ic − x̄_c)²  over its POST-MIX values.  Summing the
 partials over buckets equals ``consensus_sq_stacked`` of the new params
-exactly (the consensus distance decomposes per coordinate), so the engine
+exactly (the consensus distance decomposes per coordinate), so the simulator
 caches the folded (n,) vector and the next probe takes a host-side √mean
 instead of dispatching the standalone probe executable.
 """
@@ -74,7 +73,7 @@ _F32_BYTES = 4  # layout accounting is dtype-independent by design
 # cross-module collectives at a global rendezvous, and queueing hundreds
 # of collective-bearing launches at once can strand a rank there (7 of 8
 # waiting at a permute while the scheduler never runs the 8th) even with
-# the token chain in place.  Both engines therefore block on the token of
+# the token chain in place.  The simulator therefore blocks on the token of
 # the bucket leaving the window before dispatching a new one: at most
 # this many bucket launches are in flight — plenty to overlap bucket i's
 # permutes with bucket i+1's compute — and the host sync is on a tiny
@@ -95,16 +94,6 @@ def _leaf_sizes_stacked(tree: PyTree) -> tuple[int, ...]:
     return tuple(sizes)
 
 
-def _leaf_sizes_local(tree: PyTree) -> tuple[int, ...]:
-    sizes = []
-    for leaf in jax.tree.leaves(tree):
-        size = 1
-        for d in leaf.shape:
-            size *= int(d)
-        sizes.append(size)
-    return tuple(sizes)
-
-
 @dataclasses.dataclass(frozen=True)
 class BucketLayout:
     """Deterministic size-targeted partition of a flattened parameter tree.
@@ -114,7 +103,7 @@ class BucketLayout:
     is contiguous equal-width slices of the concatenated [0, P) vector —
     every bucket but the last has exactly ``bucket_elems`` elements, so
     the jit shape cache shares one executable across all full buckets.
-    Build via ``for_stacked`` / ``for_local`` (works on concrete arrays
+    Build via ``for_stacked`` (works on concrete arrays
     and ``ShapeDtypeStruct`` trees alike — only shapes are read).
     """
 
@@ -137,11 +126,6 @@ class BucketLayout:
     def for_stacked(cls, tree: PyTree, bucket_mb: float) -> "BucketLayout":
         """Layout for trees whose leaves carry a leading (n, ...) node axis."""
         return cls(_leaf_sizes_stacked(tree), cls.elems_for_mb(bucket_mb))
-
-    @classmethod
-    def for_local(cls, tree: PyTree, bucket_mb: float) -> "BucketLayout":
-        """Layout for one node's (un-stacked) parameter tree."""
-        return cls(_leaf_sizes_local(tree), cls.elems_for_mb(bucket_mb))
 
     # -- derived views -------------------------------------------------------
     @property
@@ -250,46 +234,9 @@ class BucketLayout:
             out.append(flat.reshape(leaf.shape).astype(leaf.dtype))
         return jax.tree.unflatten(jax.tree.structure(tree_like), out)
 
-    # -- local (per-node, inside shard_map) views ------------------------------
-    def split_local(self, tree: PyTree) -> list[jax.Array]:
-        """Bucket vectors [(w_0,), (w_1,), ...] of one node's tree."""
-        leaves = jax.tree.leaves(tree)
-        self._check(_leaf_sizes_local(tree))
-        flat = [x.reshape(-1) for x in leaves]
-        out = []
-        for segs in self.segments:
-            parts = [flat[li][s:e] for li, s, e in segs]
-            if not parts:
-                out.append(jnp.zeros((0,), jnp.float32))
-            elif len(parts) == 1:
-                out.append(parts[0])
-            else:
-                out.append(jnp.concatenate(parts))
-        return out
-
-    def merge_local(self, vecs: Sequence[jax.Array], tree_like: PyTree) -> PyTree:
-        leaves = jax.tree.leaves(tree_like)
-        self._check(_leaf_sizes_local(tree_like))
-        pieces: list[list[jax.Array]] = [[] for _ in leaves]
-        for vec, segs in zip(vecs, self.segments):
-            off = 0
-            for li, s, e in segs:
-                pieces[li].append(vec[off:off + (e - s)])
-                off += e - s
-        out = []
-        for leaf, ps in zip(leaves, pieces):
-            if not ps:
-                flat = jnp.zeros((0,), jnp.float32)
-            elif len(ps) == 1:
-                flat = ps[0]
-            else:
-                flat = jnp.concatenate(ps)
-            out.append(flat.reshape(leaf.shape).astype(leaf.dtype))
-        return jax.tree.unflatten(jax.tree.structure(tree_like), out)
-
 
 # ---------------------------------------------------------------------------
-# The per-bucket executor (shared by both engines)
+# The per-bucket executor (the simulator's bucketed path)
 # ---------------------------------------------------------------------------
 
 def bucket_eligible_optimizer(optimizer) -> bool:
@@ -352,7 +299,7 @@ def build_bucket_step(
     no cross-bucket dependency, so runtimes with per-op dependency
     tracking overlap bucket *i*'s permutes with bucket *i+1*'s update.
     The last bucket's ``tok'`` is the full folded Ξ² vector — the probe
-    fold costs zero extra dispatches.  ``fault`` is the engines'
+    fold costs zero extra dispatches.  ``fault`` is the simulator's
     runtime-mask pytree (``realization_arrays``): update gating and edge
     renormalization ride as runtime values, so every realization reuses
     the one executable.
@@ -364,7 +311,7 @@ def build_bucket_step(
     kernel path supports plain momentum-SGD only (the fused-apply gate);
     the interpreter path additionally handles weight decay and Nesterov.
 
-    Only ``mix_order="post"`` buckets: with "pre" mixing the engines keep
+    Only ``mix_order="post"`` buckets: with "pre" mixing the simulator keeps
     the monolithic step (descent must follow the full-tree mix there, so
     there is nothing to pipeline behind).
     """

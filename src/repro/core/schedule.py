@@ -549,58 +549,6 @@ class GossipProgram:
 
         return jax.tree.map(_mix, local)
 
-    # -- bucketed interpreters (overlap-scheduled gossip) --------------------
-    # Each bucket's mixing runs as its own dispatch over a contiguous slice
-    # of the flattened tree (``core.buckets.BucketLayout``): bucket i's
-    # collectives carry NO data dependency on bucket j's compute, so the
-    # engines pipeline per-bucket update+mix dispatches instead of one
-    # monolithic tail barrier.  These delegate to the per-bucket matrix
-    # applies, so ``FusedProgram`` inherits them (its overridden
-    # ``apply_stacked``/``apply_masked`` run every stage inside the SAME
-    # per-bucket dispatch — fusion composes with bucketing).
-
-    def apply_stacked_bucketed(self, stacked: PyTree, layout) -> PyTree:
-        """``apply_stacked`` split into one dispatch per layout bucket."""
-        if self.is_identity and self.self_weight == 1.0:
-            return stacked
-        mats = layout.split_stacked(stacked)
-        return layout.merge_stacked(
-            [self.apply_stacked(m) for m in mats], stacked
-        )
-
-    def apply_masked_bucketed(
-        self, stacked: PyTree, alive, *, link_up=None, layout
-    ) -> PyTree:
-        """``apply_masked`` per bucket — masks stay runtime operands, so the
-        executable set is still one per (program, bucket width)."""
-        mats = layout.split_stacked(stacked)
-        return layout.merge_stacked(
-            [self.apply_masked(m, alive, link_up=link_up) for m in mats],
-            stacked,
-        )
-
-    def apply_shard_bucketed(self, local: PyTree, axis_names, layout) -> PyTree:
-        """``apply_shard`` as one ppermute chain per bucket: the collectives
-        for bucket i commute with bucket j's compute in the schedule."""
-        if self.is_identity and self.self_weight == 1.0:
-            return local
-        vecs = layout.split_local(local)
-        return layout.merge_local(
-            [self.apply_shard(v, axis_names) for v in vecs], local
-        )
-
-    def apply_shard_masked_bucketed(
-        self, local: PyTree, axis_names, alive, *, link_up=None, layout
-    ) -> PyTree:
-        vecs = layout.split_local(local)
-        return layout.merge_local(
-            [
-                self.apply_shard_masked(v, axis_names, alive, link_up=link_up)
-                for v in vecs
-            ],
-            local,
-        )
-
 
 @lru_cache(maxsize=512)
 def _degrade_cached(program: GossipProgram, alive: tuple) -> GossipProgram:

@@ -415,7 +415,9 @@ def fused_apply_stacked(
 ):
     """One fused momentum-SGD + gossip round for a compiled PPermute program.
 
-    Flattens the stacked trees to (n, P) (zero-padded to a block multiple),
+    The kernel's single-process test entry, not a trainer path: the tests
+    drive the kernel through it against the dense oracle.  Flattens the
+    stacked trees to (n, P) (zero-padded to a block multiple),
     gathers each node's neighbor landing buffers per the program's
     ``permute_tables`` — for ``mix_order="post"`` the wire carries the
     *post-update* θ\\*, for ``"pre"`` the raw θ, so nothing extra is

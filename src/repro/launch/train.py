@@ -38,7 +38,6 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import time
-from collections import deque
 from typing import Any, Callable, Optional
 
 import jax
@@ -107,7 +106,13 @@ class _LazyStep:
 
 
 class SPMDTrainer:
-    """Builds and runs the sharded train step for one (arch × mesh × topology)."""
+    """Builds and runs the sharded train step for one (arch × mesh × topology).
+
+    One step function, ``_node_step``, in one realization per gossip size:
+    with one gossip node it is jitted as is, and with more it runs under
+    ``shard_map``, manual over the gossip axes.  Bucketed (overlap-scheduled)
+    gossip is the simulator's alone (``core/buckets``).
+    """
 
     def __init__(
         self,
@@ -125,7 +130,6 @@ class SPMDTrainer:
         hub_balance: bool = False,
         fused_apply: bool = False,
         donate: bool = True,
-        bucket_mb: Optional[float] = None,
         debug_no_retrace: bool = False,
         telemetry=None,
     ):
@@ -151,22 +155,6 @@ class SPMDTrainer:
         AllReduce/GatherRow ops and non-mixing steps keep the interpreter
         path.  Requires plain momentum-SGD (the kernel re-implements the
         update); the dense-interpreter oracle remains the correctness bar.
-
-        bucket_mb: overlap-scheduled gossip — run each mixing step as a
-        chain of per-bucket update+gossip dispatches over a
-        ``core/buckets.BucketLayout`` partition of the flattened parameter
-        vector instead of one monolithic tail (bucket i's permutes carry
-        no data dependency on bucket i+1's compute, so the dispatches
-        pipeline), folding each bucket's Ξ² partial into its pass so
-        fault-free closed-loop probes skip the standalone probe
-        executable.  Composes with ``fused_apply`` (the kernel runs per
-        bucket), ``mix_rounds`` (every stage of the fused round runs
-        inside the same per-bucket dispatch), and fault masks (runtime
-        operands — executables stay one per (program, bucket width), never
-        buckets × faults).  SGD family + ``mix_order="post"`` only;
-        active in the stacked GSPMD realization (the shard_map realization
-        keeps the monolithic step — its per-bucket schedule lives in
-        ``GossipProgram.apply_shard_bucketed`` for manual-axes meshes).
 
         Fault injection rides on the topology (``topology.fault_model``):
         the trainer draws the same seeded realization stream as the
@@ -219,7 +207,6 @@ class SPMDTrainer:
             topology.controller.bind_recorder(self.telemetry)
         self._pn_bytes: Optional[int] = None
         self._last_program = None
-        self._pending_grads = None
         self.fused_apply = bool(fused_apply)
         if self.fused_apply:
             hyper = optimizer.hyper or {}
@@ -234,25 +221,6 @@ class SPMDTrainer:
                     f"{optimizer.name}"
                 )
             self._fused_beta = float(hyper.get("momentum", 0.0))
-        self.bucket_mb = bucket_mb
-        if bucket_mb is not None:
-            from repro.core.buckets import bucket_eligible_optimizer
-
-            if not bucket_eligible_optimizer(optimizer):
-                raise ValueError(
-                    "bucket_mb requires an SGD-family optimizer (elementwise "
-                    f"update; got {optimizer.name})"
-                )
-            if topology.centralized:
-                raise ValueError("bucket_mb needs a decentralized topology")
-            if topology.mix_order != "post":
-                raise ValueError(
-                    "bucket_mb requires mix_order='post' (pre-mixing must see "
-                    "the full tree before the update)"
-                )
-        self._bucket_layout = None
-        self._folded_sq = None
-        self._folded_for_step = -1
         self.donate = donate
         self.gossip_axes = gossip_axes_for(cfg.name, mesh)
         self.g = gossip_size(mesh, self.gossip_axes)
@@ -261,7 +229,6 @@ class SPMDTrainer:
                 f"topology has {topology.n_nodes} nodes but mesh gossip axes "
                 f"{self.gossip_axes} give {self.g}"
             )
-        self.use_shard_map = self.g > 1
         tp = mesh.shape.get("model", 1)
         self.defs = tfm.model_defs(cfg, tp_size=tp)
         self.loss_fn = loss_fn or (lambda p, b: tfm.loss_fn(p, cfg, b))
@@ -443,7 +410,7 @@ class SPMDTrainer:
             )(key)
         return TrainState(p, o, 0)
 
-    # -- per-node grads (shared by both realizations) ----------------------------
+    # -- per-node grads ---------------------------------------------------------
     def _grads_of(self, params, batch):
         accum = self.accum_steps
         if accum == 1:
@@ -502,7 +469,7 @@ class SPMDTrainer:
     def _use_fused(self, program: Optional[GossipProgram]) -> bool:
         return self._fused_split(program) is not None
 
-    # -- the node-level step (shard_map realization) ------------------------------
+    # -- the node-level step (jitted at g == 1, under shard_map at g > 1) --------
     def _node_step(self, program: Optional[GossipProgram], faulty: bool = False):
         topo = self.topology
         opt = self.optimizer
@@ -571,303 +538,6 @@ class SPMDTrainer:
             return node_step
         return lambda p, o, b, lr: node_step(p, o, b, lr)
 
-    # -- the stacked step (GSPMD realization; old-jax fallback) -------------------
-    def _stacked_step(self, program: Optional[GossipProgram], faulty: bool = False):
-        """vmap over the gossip axis + the program's stacked interpreter.
-
-        Numerically identical to the shard_map realization; on a mesh whose
-        gossip axes shard the leading dim, XLA lowers the program's rolls to
-        collective-permutes (and the GatherRow einsum to an all-gather).
-        """
-        topo = self.topology
-        opt = self.optimizer
-        fused = self._fused_split(program)
-
-        def stacked_step(params, opt_state, batch, lr, fault=None):
-            loss, grads = jax.vmap(self._grads_of)(params, batch)
-            norms = (
-                jax.vmap(dbench.param_l2_norms)(params)
-                if self.collect_norms
-                else jnp.zeros((self.g, 0), jnp.float32)
-            )
-            if topo.centralized:
-                grads = jax.tree.map(
-                    lambda g: jnp.broadcast_to(
-                        g.mean(axis=0, keepdims=True), g.shape
-                    ),
-                    grads,
-                )
-
-            @profile.scope("gossip")
-            def _mix(tree, stage=program):
-                if fault is None:
-                    return stage.apply_stacked(tree)
-                return stage.apply_masked(
-                    tree, fault["alive"], link_up=fault["link"]
-                )
-
-            if fused:
-                from repro.kernels.gossip_update import fused_apply_stacked
-
-                first, rest = fused
-                with profile.scope("fused_update"):
-                    new_p, new_o = fused_apply_stacked(
-                        first, params, grads, opt_state,
-                        lr=lr, beta=self._fused_beta, fault=fault,
-                        mix_order=topo.mix_order,
-                    )
-                for stage in rest:
-                    new_p = _mix(new_p, stage)
-                return new_p, new_o, loss, norms
-            if topo.mix_order == "pre" and program is not None:
-                params = _mix(params)
-            with profile.scope("optimizer"):
-                new_p, new_o = jax.vmap(opt.update, in_axes=(0, 0, 0, None))(
-                    grads, opt_state, params, lr
-                )
-            if fault is not None:
-                u = fault["update"]
-
-                def _gate(nw, od):
-                    ucol = u.reshape((self.g,) + (1,) * (nw.ndim - 1))
-                    return jnp.where(ucol > 0, nw, od)
-
-                new_p = jax.tree.map(_gate, new_p, params)
-                new_o = jax.tree.map(_gate, new_o, opt_state)
-            if topo.mix_order == "post" and program is not None:
-                new_p = _mix(new_p)
-            return new_p, new_o, loss, norms
-
-        if faulty:
-            return stacked_step
-        return lambda p, o, b, lr: stacked_step(p, o, b, lr)
-
-    # -- bucketed, overlap-scheduled path (stacked realization) ---------------
-    @property
-    def _bucketed(self) -> bool:
-        return (
-            self.bucket_mb is not None
-            and self.g > 1
-            and not self.use_shard_map
-        )
-
-    def _bucket_grads_fn(self, batch: PyTree):
-        """The jitted backward: (loss, grads, norms) — the compute the
-        per-bucket mixing dispatches pipeline behind."""
-        key = "__bucket_grads__"
-        if key not in self._step_cache:
-            gvec = NamedSharding(self.mesh, P(self.gossip_axes))
-
-            def gn(params, batch):
-                loss, grads = jax.vmap(self._grads_of)(params, batch)
-                norms = (
-                    jax.vmap(dbench.param_l2_norms)(params)
-                    if self.collect_norms
-                    else jnp.zeros((self.g, 0), jnp.float32)
-                )
-                return loss, grads, norms
-
-            self._step_cache[key] = jax.jit(
-                gn,
-                in_shardings=(
-                    self.param_shardings,
-                    jax.tree.map(
-                        lambda x: shd.batch_sharding(
-                            self.mesh, self.gossip_axes, len(x.shape),
-                            stacked=True,
-                        ),
-                        batch,
-                    ),
-                ),
-                # grads mirror the parameter tree leaf-for-leaf
-                out_shardings=(gvec, self.param_shardings, gvec),
-            )
-        return self._step_cache[key]
-
-    def _bucket_fn(self, program, width: int, has_m: bool, faulty: bool):
-        """One bucket width's jitted update+mix dispatch, cached per
-        (program, width): all full buckets share one executable, the tail
-        adds at most a second; fault masks ride as runtime operands."""
-        key = ("__bucket__", program.cache_key, width, has_m, faulty)
-        if key not in self._step_cache:
-            from repro.core.buckets import build_bucket_step
-
-            kernel_split = (
-                self._fused_split(program) if self.fused_apply else None
-            )
-            fn = build_bucket_step(
-                program,
-                hyper=self.optimizer.hyper,
-                has_momentum=has_m,
-                faulty=faulty,
-                kernel_split=kernel_split,
-            )
-            lead2 = NamedSharding(self.mesh, P(self.gossip_axes, None))
-            gvec = NamedSharding(self.mesh, P(self.gossip_axes))
-            rep = NamedSharding(self.mesh, P())
-            ins = (
-                [lead2, lead2, rep, gvec]
-                if not has_m
-                else [lead2, lead2, lead2, rep, gvec]
-            )
-            if faulty:
-                ins.append({
-                    "update": rep, "alive": rep,
-                    "link": rep if self.fault_model.has_link_faults else None,
-                })
-            outs = (lead2, lead2, gvec) if has_m else (lead2, gvec)
-            self._step_cache[key] = jax.jit(
-                fn,
-                in_shardings=tuple(ins),
-                out_shardings=outs,
-                donate_argnums=((0, 1) if has_m else (0,)) if self.donate else (),
-            )
-        return self._step_cache[key]
-
-    def _bucket_split_fn(self, state, grads, has_m: bool):
-        """Jitted bucket-view builder: canonical (model-sharded) trees in,
-        (G, w) bucket matrices out.  One executable (not one per leaf):
-        the model-axis gathers the reshapes imply stay INSIDE it, so they
-        are ordered by its data dependencies — loose eager reshapes would
-        each be their own collective-bearing dispatch, outside the token
-        chain (see ``_bucketed_step``), and could interleave differently
-        across devices and deadlock."""
-        key = ("__bucket_split__", has_m)
-        if key not in self._step_cache:
-            layout = self._bucket_layout
-            lead2 = NamedSharding(self.mesh, P(self.gossip_axes, None))
-
-            def split3(params, opt, g):
-                return (
-                    layout.split_stacked(params),
-                    layout.split_stacked(opt) if has_m else [],
-                    layout.split_stacked(g),
-                )
-
-            nb = layout.num_buckets
-            self._step_cache[key] = jax.jit(
-                split3,
-                in_shardings=(
-                    self.param_shardings,
-                    self.opt_shardings if has_m else (),
-                    self.param_shardings,
-                ),
-                out_shardings=(
-                    [lead2] * nb, [lead2] * nb if has_m else [], [lead2] * nb
-                ),
-            )
-        return self._step_cache[key]
-
-    def _bucket_merge_fn(self, state, has_m: bool):
-        """Jitted inverse: bucket matrices back into canonically-sharded
-        trees.  Consumes the Ξ² token, so it is ordered after the last
-        bucket dispatch; passes it through for the probe fold."""
-        key = ("__bucket_merge__", has_m)
-        if key not in self._step_cache:
-            layout = self._bucket_layout
-            lead2 = NamedSharding(self.mesh, P(self.gossip_axes, None))
-            gvec = NamedSharding(self.mesh, P(self.gossip_axes))
-            p_tmpl = jax.tree.map(
-                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), state.params
-            )
-            o_tmpl = jax.tree.map(
-                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-                state.opt_state,
-            )
-            nb = layout.num_buckets
-
-            def merge3(ts, ms, tok):
-                p = layout.merge_stacked(ts, p_tmpl)
-                o = layout.merge_stacked(ms, o_tmpl) if has_m else ()
-                return p, o, tok
-
-            self._step_cache[key] = jax.jit(
-                merge3,
-                in_shardings=(
-                    [lead2] * nb, [lead2] * nb if has_m else [], gvec
-                ),
-                out_shardings=(
-                    self.param_shardings,
-                    self.opt_shardings if has_m else (),
-                    gvec,
-                ),
-            )
-        return self._step_cache[key]
-
-    def _bucketed_step(self, state, batch, lr, program, fault):
-        """One iteration as a pipelined chain of per-bucket dispatches.
-
-        The backward dispatch runs first; a jitted split carves the
-        canonical trees into (G, w) bucket matrices; then each bucket's
-        update + all its gossip rounds + its Ξ² partial launches as its
-        own executable; a jitted merge re-places the canonical trees.
-        The (G,) Ξ² accumulator token is the only cross-bucket operand:
-        it pins a consistent execution order across devices (independent
-        collective-bearing executables can otherwise start in different
-        per-device orders and deadlock at the permute rendezvous), while
-        the (G, w) payloads stay independent, so the runtime overlaps
-        bucket i's collective-permutes with bucket i+1's compute instead
-        of serializing communication behind one monolithic tail.  The
-        dispatch window is bounded (``MAX_INFLIGHT_BUCKETS``): before
-        launching a new bucket the host blocks on the token of the one
-        leaving the window, so fine bucket sizes cannot queue hundreds
-        of collective-bearing launches at once.
-        """
-        from repro.core.buckets import MAX_INFLIGHT_BUCKETS, BucketLayout
-
-        if self._bucket_layout is None:
-            self._bucket_layout = BucketLayout.for_stacked(
-                state.params, self.bucket_mb
-            )
-        layout = self._bucket_layout
-        with jax.set_mesh(self.mesh):
-            loss, grads, norms = self._bucket_grads_fn(batch)(
-                state.params, batch
-            )
-            # the bucketed path is the one place grads materialize outside
-            # the fused step executable — stash them for the grad-norm
-            # gauge (host work deferred to the post-step metrics emission)
-            self._pending_grads = (
-                grads if self.telemetry.due(state.step) else None
-            )
-            has_m = state.opt_state != ()
-            t_mats, m_mats, g_mats = self._bucket_split_fn(state, grads, has_m)(
-                state.params, state.opt_state, grads
-            )
-            lr32 = jnp.float32(lr)
-            gvec = NamedSharding(self.mesh, P(self.gossip_axes))
-            tok = jax.device_put(jnp.zeros((self.g,), jnp.float32), gvec)
-            out_t, out_m = [], []
-            window: deque = deque()
-            for b, w in enumerate(layout.widths):
-                if len(window) >= MAX_INFLIGHT_BUCKETS:
-                    jax.block_until_ready(window.popleft())
-                fn = self._bucket_fn(program, w, has_m, fault is not None)
-                args = (
-                    (t_mats[b], m_mats[b], g_mats[b], lr32, tok)
-                    if has_m
-                    else (t_mats[b], g_mats[b], lr32, tok)
-                )
-                if fault is not None:
-                    args = args + (fault,)
-                res = fn(*args)
-                if has_m:
-                    t2, m2, tok = res
-                    out_m.append(m2)
-                else:
-                    t2, tok = res
-                out_t.append(t2)
-                window.append(tok)
-            new_params, new_opt, tok = self._bucket_merge_fn(state, has_m)(
-                out_t, out_m, tok
-            )
-            if not has_m:
-                new_opt = state.opt_state
-        if fault is None:
-            self._folded_sq = tok
-            self._folded_for_step = state.step + 1
-        return new_params, new_opt, loss, norms
-
     # -- jitted step per program ----------------------------------------------
     def step_fn(self, epoch: int = 0, batch_abstract: Optional[PyTree] = None,
                 *, step: int = 0, mix: bool = True, program_alive=None):
@@ -932,7 +602,7 @@ class SPMDTrainer:
             def build(batch_tree):
                 return jit_step(node_step, batch_tree)
 
-        elif self.use_shard_map:
+        else:
             node_step = self._node_step(program, faulty=faulty)
 
             def build(batch_tree):
@@ -954,12 +624,6 @@ class SPMDTrainer:
                     check_vma=False,
                 )
                 return jit_step(mapped, batch_tree)
-
-        else:
-            stacked_step = self._stacked_step(program, faulty=faulty)
-
-            def build(batch_tree):
-                return jit_step(stacked_step, batch_tree)
 
         fn = _LazyStep(build, self.mesh)
         self._step_cache[key] = fn
@@ -983,7 +647,7 @@ class SPMDTrainer:
         ``_record_round``): closes the ``round`` span — blocking on the
         loss so the measured duration covers the whole dispatched round,
         with deadline-overrun attribution in the recorder — and emits the
-        loss/lr/variance/grad-norm sample at the metrics cadence.  Purely
+        loss/lr/variance sample at the metrics cadence.  Purely
         observational; the averaging masks stay seeded."""
         tel = self.telemetry
         if t_start is not None:
@@ -993,9 +657,7 @@ class SPMDTrainer:
             tel.step_metrics(
                 step, loss=loss, lr=lr,
                 norms=norms if self.collect_norms else None,
-                grads=self._pending_grads,
             )
-            self._pending_grads = None
 
     def _realize_faults(self, state: TrainState, epoch: int):
         """This step's fault realization, applied to the state: recovered
@@ -1066,13 +728,6 @@ class SPMDTrainer:
                     state.params,
                     jnp.asarray(np.asarray(fr.alive) != 0, jnp.float32),
                 )
-            elif self._folded_for_step == state.step:
-                # folded probe: the last bucketed mixing step already
-                # accumulated each bucket's Ξ² partial in its own
-                # dispatch — only the final √mean runs, on the host
-                from repro.core.buckets import xi_from_folded_sq
-
-                xi = xi_from_folded_sq(self._folded_sq)
             else:
                 from repro.core.consensus import consensus_distance_jit
 
@@ -1080,14 +735,6 @@ class SPMDTrainer:
         if self.telemetry.active:
             self.telemetry.gauge("xi", float(xi), step=state.step)
         ctl.observe(float(xi), state.step)
-
-    def _bucketed_warm(self, program, has_m: bool, faulty: bool) -> bool:
-        """True when every executable of a bucketed step is already built."""
-        layout = self._bucket_layout
-        return layout is not None and all(
-            ("__bucket__", program.cache_key, w, has_m, faulty) in self._step_cache
-            for w in set(layout.widths)
-        )
 
     def train_step(self, state: TrainState, batch: PyTree, lr: float, *, epoch: int = 0):
         """One training step, inside the host span ``repro.train_step``
@@ -1115,26 +762,6 @@ class SPMDTrainer:
             # branch — and any extra executable — is never taken
             sel = fr.selection_mask() if fr is not None else None
             palive = sel if sel is not None and not sel.all() else None
-            if self._bucketed and mix and not self.topology.centralized:
-                program = self._program_at(state.step // self.mix_every, epoch)
-                if program is not None and palive is not None:
-                    program = program.degrade(palive)
-                if program is not None:
-                    from repro.core.faults import realization_arrays
-
-                    self._bill_comm(program, state.params, state.step, fr)
-                    fault = realization_arrays(fr) if fr is not None else None
-                    warm = self._bucketed_warm(
-                        program, state.opt_state != (), fault is not None
-                    )
-                    with self._call_span(warm, state.step):
-                        p, o, loss, norms = self._bucketed_step(
-                            state, batch, lr, program, fault
-                        )
-                    self._finish_round(
-                        loss, norms, t_start, step=state.step, mix=True, lr=lr
-                    )
-                    return TrainState(p, o, state.step + 1), loss, norms
             fn = self.step_fn(
                 epoch, step=state.step // self.mix_every,
                 mix=mix or self.topology.centralized,
@@ -1170,16 +797,13 @@ class SPMDTrainer:
         replaying from the checkpoint step regenerates them bit-exactly.
 
         ``run_config`` records the load-bearing launch configuration
-        (topology name, gossip size, bucket layout) so a mismatched
+        (topology name, gossip size) so a mismatched
         ``--resume`` fails fast at restore with a clear error instead of
         surfacing as a shape/tree mismatch mid-run."""
         d: dict = {
             "run_config": {
                 "topology": self.topology.name,
                 "n": int(self.g),
-                "bucket_mb": (
-                    None if self.bucket_mb is None else float(self.bucket_mb)
-                ),
             },
             "last_membership": (
                 None if self._last_membership is None
@@ -1197,10 +821,15 @@ class SPMDTrainer:
 
         Validates the checkpoint's recorded ``run_config`` against this
         trainer's configuration first (fail-fast resume)."""
-        rc = d.get("run_config") or {}
+        # only the keys this trainer writes: older ones also recorded a
+        # bucket size that their step never used
+        rc = {
+            k: v for k, v in (d.get("run_config") or {}).items()
+            if k in ("topology", "n")
+        }
         _validate_run_config(
             rc, topology=self.topology.name, n=int(self.g),
-            bucket_mb=self.bucket_mb, n_label="mesh gossip size",
+            n_label="mesh gossip size",
         )
         lm = d.get("last_membership")
         self._last_membership = (
@@ -1331,13 +960,6 @@ def main(argv: Optional[list] = None) -> RunResult:
     ap.add_argument("--fused-apply", action="store_true",
                     help="run optimizer+gossip as one fused Pallas pass for "
                          "all-PPermute programs (plain momentum-SGD only)")
-    ap.add_argument("--bucket-mb", type=float, default=None,
-                    help="overlap-scheduled gossip: partition the flattened "
-                         "parameter vector into ~this-many-MiB buckets and "
-                         "pipeline per-bucket update+permute dispatches "
-                         "instead of one monolithic mixing tail (folds the "
-                         "consensus probe into the gossip pass; SGD family "
-                         "+ post-mixing only)")
     ap.add_argument("--fault-model", default="none",
                     choices=["none", "crash", "concurrent", "preempt",
                              "join", "deadline", "dropout", "link",
@@ -1525,8 +1147,7 @@ def main(argv: Optional[list] = None) -> RunResult:
         cfg, mesh, topo, get_optimizer(args.optimizer), collect_norms=True,
         mixing=args.mixing, mix_every=args.mix_every,
         mix_rounds=args.mix_rounds, hub_balance=args.hub_balance,
-        fused_apply=args.fused_apply,
-        bucket_mb=args.bucket_mb, telemetry=recorder,
+        fused_apply=args.fused_apply, telemetry=recorder,
     )
     n_params = sum(
         int(np.prod(x.shape)) for x in jax.tree.leaves(abstract_params(trainer.defs))
@@ -1561,10 +1182,7 @@ def main(argv: Optional[list] = None) -> RunResult:
         apply_mode = "fused-pallas"
     elif args.fused_apply:
         apply_mode = "interpreter (program not fused-eligible)"
-    if trainer._bucketed:
-        apply_mode += f" | bucketed {args.bucket_mb}MiB"
     print(topo.describe(), "| mesh", dict(mesh.shape), "| mixing", args.mixing,
-          "| engine", "shard_map" if trainer.use_shard_map else "stacked",
           "| rounds", args.mix_rounds, "| apply", apply_mode)
     n_progs = len(trainer.precompile_programs(args.steps // args.steps_per_epoch + 1))
     print(f"{n_progs} distinct mixing program(s) over the run")

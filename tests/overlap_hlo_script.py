@@ -1,16 +1,13 @@
 """Subprocess body for test_spmd.py: bucketed-overlap lowering + probe fold.
 
-Locks in the overlap-scheduling acceptance bar:
-  1. the bucketed shard interpreter lowers to collective-permutes ONLY —
-     one ppermute chain per bucket, ``ops × buckets`` permutes, zero
-     all-gathers — and matches the dense mixing-matrix oracle;
-  2. a single per-bucket executor (``build_bucket_step`` under GSPMD)
+Locks in the simulator's overlap-scheduling acceptance bar:
+  1. a single per-bucket executor (``build_bucket_step`` under GSPMD)
      carries its gossip permutes AND the optimizer compute in the SAME
      executable — the dispatch-pipelining evidence: bucket i's permutes
      have no dependency on bucket i+1's compute, only the tiny Ξ² token
      chains them — with no all-gather and at most the fold's one
      all-reduce;
-  3. the Ξ_t probe fold removes the standalone probe executable from a
+  2. the Ξ_t probe fold removes the standalone probe executable from a
      closed-loop run: with ``bucket_mb`` set, ``consensus_distance_jit``
      runs only for the very first probe (no fold exists yet); every later
      probe reads the token accumulated inside the bucket dispatches, and
@@ -29,47 +26,20 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.core.buckets import BucketLayout, build_bucket_step
+from repro.core.buckets import build_bucket_step
 from repro.core.graphs import Ring
 from repro.core.schedule import compile_graph
-from repro.launch.hlo_analysis import assert_no_all_gather, collective_counts
+from repro.launch.hlo_analysis import collective_counts
 from repro.launch.mesh import make_mesh
 from repro.optim.sgd import sgd
 
 N = 8
 mesh = make_mesh((N,), ("gossip",))
 
-# --- 1. bucketed shard interpreter: permutes only, ops x buckets ------------
 prog = compile_graph(Ring(N))
 rng = np.random.default_rng(0)
-local_tmpl = {"a": np.zeros((5, 3), np.float32), "b": np.zeros((17,), np.float32)}
-layout = BucketLayout.for_local(local_tmpl, 10 * 4 / (1 << 20))  # 10-elem buckets
-assert layout.num_buckets == 4, layout.widths
 
-x = {
-    "a": rng.normal(size=(N, 5, 3)).astype(np.float32),
-    "b": rng.normal(size=(N, 17)).astype(np.float32),
-}
-f = jax.jit(
-    jax.shard_map(
-        lambda v: prog.apply_shard_bucketed(v, "gossip", layout),
-        mesh=mesh, check_vma=False, in_specs=P("gossip"), out_specs=P("gossip"),
-    )
-)
-xj = jax.tree.map(jnp.asarray, x)
-counts = assert_no_all_gather(f, xj)
-want_permutes = len(prog.ops) * layout.num_buckets
-assert counts.get("collective-permute", 0) == want_permutes, (counts, want_permutes)
-got = jax.device_get(f(xj))
-W = prog.matrix()
-for k in x:
-    want = np.einsum("ij,j...->i...", W, x[k])
-    err = float(np.abs(got[k] - want).max())
-    assert err < 1e-5, (k, err)
-print(f"bucketed shard interpreter: {want_permutes} permutes "
-      f"({len(prog.ops)} ops x {layout.num_buckets} buckets), no all-gather")
-
-# --- 2. per-bucket executor: permutes + compute in ONE executable -----------
+# --- 1. per-bucket executor: permutes + compute in ONE executable -----------
 WIDTH = 96
 lead2 = NamedSharding(mesh, P("gossip", None))
 rep = NamedSharding(mesh, P())
@@ -96,7 +66,7 @@ assert any(op in compiled for op in ("fusion", "subtract", "multiply")), (
 print(f"per-bucket executor: {len(prog.ops)} permutes + optimizer compute "
       "in one executable, no all-gather")
 
-# --- 3. probe fold: no standalone probe executable in closed-loop runs ------
+# --- 2. probe fold: no standalone probe executable in closed-loop runs ------
 from repro.core import consensus
 from repro.core.dsgd import make_topology
 from repro.core.simulator import DecentralizedSimulator

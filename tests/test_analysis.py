@@ -319,7 +319,6 @@ def test_used_program_keys_unwraps_engine_taxonomy():
         ("__bucket__", key, 128, True, False): 1,   # bucketed executable
         ("__local__", 8): 1,                        # internal closure
         (("__local__", 8), "faulty"): 1,
-        "__bucket_grads__": 1,                      # SPMD string key
         None: 1,                                    # programless trainer key
     }
     assert used_program_keys(cache) == {key}

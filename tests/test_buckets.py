@@ -1,9 +1,10 @@
 """Bucketed, overlap-scheduled gossip (core/buckets.py).
 
-Covers: ``BucketLayout`` round-trips exactly on random pytrees (stacked
-and local views, buckets crossing leaf boundaries, dtype preservation,
-<= 2 distinct widths so the jit shape cache stays at <= 2 executables per
-program), the bucketed ``apply_*`` interpreters == the monolithic apply ==
+Covers: ``BucketLayout`` round-trips exactly on random pytrees (buckets
+crossing leaf boundaries, dtype preservation, <= 2 distinct widths so the
+jit shape cache stays at <= 2 executables per program), the per-bucket
+``apply_*`` (split -> apply -> merge, as ``build_bucket_step`` runs it) ==
+the monolithic apply ==
 the dense mixing-matrix oracle <= 1e-6 on random connected graphs —
 including the runtime-masked fault paths — the per-bucket executor
 (``build_bucket_step``) against a hand-rolled SGD+mix oracle for every
@@ -71,8 +72,8 @@ def _random_tree(rng, n, n_leaves, dtype=np.float32):
 )
 @settings(max_examples=40, deadline=None)
 def test_layout_roundtrip_is_identity(n, n_leaves, bucket_elems, seed):
-    """split -> merge == identity on random pytrees, for both the stacked
-    (n, ...) and the local per-node views, at every bucket width."""
+    """split -> merge == identity on random (n, ...) pytrees, at every
+    bucket width."""
     rng = np.random.default_rng(seed)
     tree = _random_tree(rng, n, n_leaves)
     layout = BucketLayout(
@@ -85,11 +86,6 @@ def test_layout_roundtrip_is_identity(n, n_leaves, bucket_elems, seed):
     for k in tree:
         np.testing.assert_array_equal(np.asarray(back[k]), np.asarray(tree[k]))
         assert back[k].dtype == tree[k].dtype
-    local = {k: v[0] for k, v in tree.items()}
-    vecs = layout.split_local(local)
-    back_l = layout.merge_local(vecs, local)
-    for k in local:
-        np.testing.assert_array_equal(np.asarray(back_l[k]), np.asarray(local[k]))
 
 
 @given(
@@ -153,8 +149,9 @@ def test_layout_rejects_mismatched_tree():
 )
 @settings(max_examples=30, deadline=None)
 def test_bucketed_apply_matches_monolithic_and_dense_oracle(n, seed, be):
-    """ISSUE acceptance: on random connected graphs, per-bucket apply ==
-    monolithic apply == W @ x <= 1e-6, fault-free and runtime-masked."""
+    """On random connected graphs, per-bucket apply (split -> apply ->
+    merge, as ``build_bucket_step`` runs it) == monolithic apply == W @ x
+    <= 1e-6, fault-free and runtime-masked."""
     rng = np.random.default_rng(seed)
     g = _random_connected_graph(n, seed)
     prog = compile_graph(g)
@@ -174,7 +171,8 @@ def test_bucketed_apply_matches_monolithic_and_dense_oracle(n, seed, be):
         )
 
     # fault-free
-    got = prog.apply_stacked_bucketed(tree, layout)
+    mats = layout.split_stacked(tree)
+    got = layout.merge_stacked([prog.apply_stacked(m) for m in mats], tree)
     mono = prog.apply_stacked(tree)
     np.testing.assert_allclose(_flat(got), _flat(mono), atol=1e-6)
     np.testing.assert_allclose(_flat(got), w @ flat, atol=1e-6)
@@ -188,7 +186,9 @@ def test_bucketed_apply_matches_monolithic_and_dense_oracle(n, seed, be):
     af = jnp.asarray(alive, jnp.float32)
     lf = jnp.asarray(link, jnp.float32)
     wd = degraded_matrix(w, alive, link)
-    got_m = prog.apply_masked_bucketed(tree, af, link_up=lf, layout=layout)
+    got_m = layout.merge_stacked(
+        [prog.apply_masked(m, af, link_up=lf) for m in mats], tree
+    )
     mono_m = prog.apply_masked(tree, af, link_up=lf)
     np.testing.assert_allclose(_flat(got_m), _flat(mono_m), atol=1e-6)
     np.testing.assert_allclose(_flat(got_m), wd @ flat, atol=1e-5)
@@ -200,7 +200,8 @@ def test_bucketed_apply_identity_program_shortcircuits():
     prog = identity_program(4)
     layout = BucketLayout((6,), 5)
     x = jnp.arange(24.0).reshape(4, 6)
-    assert prog.apply_stacked_bucketed(x, layout) is x
+    assert prog.apply_stacked(x) is x
+    assert all(prog.apply_stacked(m) is m for m in layout.split_stacked(x))
 
 
 # ---------------------------------------------------------------------------

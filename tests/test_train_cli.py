@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.launch.train import build_config, main
+from repro.launch.train import SPMDTrainer, build_config, main
 
 ONE_NODE = [
     "--arch", "granite-8b", "--reduced", "--mesh", "1,1",
@@ -29,12 +29,38 @@ def test_one_node_mesh_trains(capsys):
     res = main(ONE_NODE)
     assert len(res.losses) == len(res.step_seconds) == 3
     assert all(l.shape == (1,) and np.isfinite(l).all() for l in res.losses)
-    assert not res.trainer.use_shard_map
+    assert res.trainer.g == 1
     init = res.trainer.init_state(jax.random.PRNGKey(0))
     assert _checksums(res.state.params) != _checksums(init.params)
     out = capsys.readouterr().out
     assert "granite-8b: 2 layers, d_model 256" in out
     assert "3 steps in" in out
+
+
+def test_resume_accepts_a_recorded_bucket_size_and_checks_the_rest():
+    """A checkpoint that recorded ``bucket_mb`` restores (the trainer never
+    bucketed, so the value changed nothing), while a changed topology or
+    gossip size still fails fast."""
+    from repro.core.dsgd import make_topology
+    from repro.launch.mesh import make_mesh
+    from repro.optim.sgd import sgd
+
+    def trainer(topo_name):
+        return SPMDTrainer(
+            build_config("granite-8b", reduced=True),
+            make_mesh((1, 1), ("data", "model")),
+            make_topology(topo_name, 1), sgd(momentum=0.9),
+        )
+
+    snap = trainer("d_ring").snapshot_extra()
+    assert snap["run_config"] == {"topology": "d_ring", "n": 1}
+    snap["run_config"]["bucket_mb"] = 2.0
+    trainer("d_ring").restore_extra(snap)
+    with pytest.raises(ValueError, match="d_ring.*c_complete"):
+        trainer("c_complete").restore_extra(snap)
+    snap["run_config"]["n"] = 2
+    with pytest.raises(ValueError, match="mesh gossip size 2"):
+        trainer("d_ring").restore_extra(snap)
 
 
 def test_layers_flag_sets_depth_in_the_run(capsys):
