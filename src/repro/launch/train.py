@@ -1244,7 +1244,8 @@ def main(argv: Optional[list] = None) -> RunResult:
         for name in profile.COUNTERS if name.startswith("attention.path.")
     ))
     print(f"WKV blocks traced into the step, chunked: {paths.get('wkv.chunked', 0)}, "
-          f"pallas: {paths.get('wkv.pallas', 0)}")
+          f"pallas: {paths.get('wkv.pallas', 0)} "
+          f"(heads packed: {paths.get('wkv.pallas.packed', 0)})")
     if trainer.round_ms:
         ms = np.asarray(trainer.round_ms)
         line = (f"round trace: median {np.median(ms):.1f}ms "

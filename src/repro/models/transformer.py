@@ -39,6 +39,7 @@ from repro.models.common import (
     rope,
     zeros_init,
 )
+from repro.kernels.wkv import heads_per_row
 from repro.models.mamba2 import (
     MambaState,
     apply_mamba_block,
@@ -396,6 +397,8 @@ def forward(
         n_heads = cfg.n_heads or cfg.d_model // 64
         wkv_impl = resolve_wkv()
         profile.count("wkv." + wkv_impl, cfg.n_layers)
+        if wkv_impl == "pallas" and heads_per_row(n_heads, cfg.d_model // n_heads) > 1:
+            profile.count("wkv.pallas.packed", cfg.n_layers)
 
         def body(carry, layer_p):
             st0 = RWKVState.empty(b, n_heads, cfg.d_model // n_heads, cfg.d_model, h.dtype)

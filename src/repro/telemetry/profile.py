@@ -64,12 +64,13 @@ SCOPES = (
     "probe",           # consensus distance
 )
 
-# trace-time counters: attention blocks by the path they resolved to, and
-# RWKV-6 blocks by the WKV path: the Pallas kernel or XLA's chunk scan
+# trace-time counters: attention blocks by the path they resolved to,
+# RWKV-6 blocks by the WKV path (the Pallas kernel or XLA's chunk scan), and
+# of the kernel's blocks those that lay more than one head on a block's lanes
 COUNTERS = tuple(
     "attention.path." + p
     for p in ("splash", "reference", "chunked", "chunked_skip")
-) + ("wkv.pallas", "wkv.chunked")
+) + ("wkv.pallas", "wkv.chunked", "wkv.pallas.packed")
 
 _traced: collections.Counter = collections.Counter()
 

@@ -119,18 +119,24 @@ def test_float8_control_is_outside_the_bound(reference):
 
 PUBLISHED_DECAY = (-6.0, 1.0)
 CLIP_DECAY = (-8.0, 6.0)  # the program's clip: log decays -e^6 to -e^-8
-# (seq, chunk of the XLA scan, batch, log decay exponent range, id): the
-# published initialisation's decay range, then the whole range of the
+# (seq, chunk of the XLA scan, batch, heads, log decay exponent range, id):
+# the published initialisation's decay range, then the whole range of the
 # program's clip, a sequence that leaves the kernel's 64-row chunk a
-# remainder, and a batch of two
+# remainder, and a batch of two.  Heads of 64: two or four share each of
+# the kernel's 128-lane blocks (its pair index and batch index both move
+# in the four-head, two-batch case, and the clip's range shows any leak
+# between heads first); three cannot pair, and take a block each.
 WKV_CASES = [
-    (64, 16, 1, PUBLISHED_DECAY, "divides"),
-    (60, 16, 1, PUBLISHED_DECAY, "remainder"),
-    (50, 64, 1, PUBLISHED_DECAY, "one_short_chunk"),
-    (96, 32, 1, PUBLISHED_DECAY, "three"),
-    (64, 64, 1, CLIP_DECAY, "clip_range"),
-    (100, 32, 1, PUBLISHED_DECAY, "two_chunks_remainder"),
-    (48, 16, 2, PUBLISHED_DECAY, "batch2"),
+    (64, 16, 1, 2, PUBLISHED_DECAY, "divides"),
+    (60, 16, 1, 2, PUBLISHED_DECAY, "remainder"),
+    (50, 64, 1, 2, PUBLISHED_DECAY, "one_short_chunk"),
+    (96, 32, 1, 2, PUBLISHED_DECAY, "three"),
+    (64, 64, 1, 2, CLIP_DECAY, "clip_range"),
+    (100, 32, 1, 2, PUBLISHED_DECAY, "two_chunks_remainder"),
+    (48, 16, 2, 2, PUBLISHED_DECAY, "batch2"),
+    (64, 16, 1, 3, PUBLISHED_DECAY, "three_heads_unpaired"),
+    (100, 32, 2, 4, PUBLISHED_DECAY, "four_heads_batch2_remainder"),
+    (128, 64, 1, 4, CLIP_DECAY, "clip_range_four_heads"),
 ]
 # Over the clip's range a chunk's cumulative log decay reaches 64 x e^6,
 # about 26,000, where a float32 step is 2e-3: both chunked paths take
@@ -143,19 +149,19 @@ CLIP_TOL = 5e-4
 def _wkv_cases():
     """Every case on the XLA chunk scan under its own id, and on the Pallas
     kernel (interpret mode) under the id with ``-pallas``."""
-    for seq, chunk, b, decay, name in WKV_CASES:
-        yield pytest.param(seq, chunk, b, decay, "chunked", id=name)
-        yield pytest.param(seq, chunk, b, decay, "pallas", id=name + "-pallas")
+    for seq, chunk, b, h, decay, name in WKV_CASES:
+        yield pytest.param(seq, chunk, b, h, decay, "chunked", id=name)
+        yield pytest.param(seq, chunk, b, h, decay, "pallas", id=name + "-pallas")
 
 
-@pytest.mark.parametrize("seq, chunk, b, decay, impl", _wkv_cases())
-def test_chunked_wkv_matches_the_scan_with_gradients(seq, chunk, b, decay, impl):
+@pytest.mark.parametrize("seq, chunk, b, h, decay, impl", _wkv_cases())
+def test_chunked_wkv_matches_the_scan_with_gradients(seq, chunk, b, h, decay, impl):
     """Output, final state and the gradients of both with respect to every
     input, at the published widths' head size, against the step-by-step
     scan; every output and gradient is finite."""
     from repro.kernels import ops
 
-    h, n = 2, 64
+    n = 64
     ks = jax.random.split(jax.random.PRNGKey(seq * 100 + chunk), 6)
     r, k, v = (jax.random.normal(ks[i], (b, seq, h, n)) for i in range(3))
     logw = -jnp.exp(jax.random.uniform(ks[3], (b, seq, h, n),
@@ -194,3 +200,20 @@ def test_chunked_wkv_matches_the_scan_with_gradients(seq, chunk, b, decay, impl)
         scale = float(jnp.max(jnp.abs(b_)))
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_), rtol=0,
                                    atol=1e-5 * scale)
+
+
+@pytest.mark.parametrize("heads, size, p", [
+    (32, 64, 2),    # the published widths: two heads a 128-lane row
+    (2, 64, 2),
+    (3, 64, 1),     # an odd head count cannot pair
+    (1, 64, 1),
+    (8, 32, 4),
+    (6, 32, 1),     # four of 32 fill a row, and four do not divide six
+    (4, 128, 1),    # a head fills the row
+    (4, 256, 1),
+    (4, 48, 1),     # 48 does not divide 128
+], ids=lambda x: str(x))
+def test_heads_per_row_packs_where_heads_fill_whole_lane_rows(heads, size, p):
+    from repro.kernels.wkv import heads_per_row
+
+    assert heads_per_row(heads, size) == p

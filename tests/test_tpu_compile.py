@@ -21,6 +21,7 @@ one process may load the TPU compiler library, and every test worker
 imports this file.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -211,7 +212,8 @@ def test_rwkv_block_grad_compiles_through_the_wkv_kernel(topo):
     mix 7168), batch 1, S 4096, bfloat16, on a 1x1 mesh of one described
     v5e: the WKV path resolves to the Pallas kernel, and its forward (with
     the per-chunk states the backward reads) and backward are compiled
-    Mosaic calls that carry the ``wkv`` scope."""
+    Mosaic calls that carry the ``wkv`` scope and read and write their
+    sequences in the model's own (B, L, H·N) layout, not transposed."""
     from repro.models.rwkv6 import (RWKVState, apply_rwkv_block, resolve_wkv,
                                     rwkv_block_defs)
 
@@ -242,3 +244,9 @@ def test_rwkv_block_grad_compiles_through_the_wkv_kernel(topo):
                 if kernel in line.split(" = ", 1)[0]]
         assert mine, kernel
         assert all("/wkv/" in line.split("op_name=", 1)[-1] for line in mine), kernel
+        for line in mine:
+            operands = line.split("operand_layout_constraints={", 1)[1].split("}}", 1)[0]
+            seqs = [dims for dtype, dims in re.findall(r"(\w+)\[([\d,]*)\]", operands)
+                    if dtype == "bf16"]
+            # r, k, v, the log decay; and the backward's output gradient
+            assert seqs == [f"1,{s},{d}"] * (4 if kernel == "wkv_fwd_states" else 5), seqs

@@ -3,7 +3,8 @@
 ``models/rwkv6.resolve_wkv`` takes the Pallas kernel on a TPU whose mesh
 leaves no axis of size > 1 to the partitioner, and XLA's chunk scan
 everywhere else; ``transformer.forward`` bills each traced block to the
-counter of its path, ``wkv.pallas`` or ``wkv.chunked``."""
+counter of its path, ``wkv.pallas`` or ``wkv.chunked``, and a kernel block
+whose heads share the kernel's lanes also to ``wkv.pallas.packed``."""
 from __future__ import annotations
 
 import jax
@@ -43,15 +44,18 @@ def _traced_counts(cfg):
     tokens = jax.ShapeDtypeStruct((1, 64), jnp.int32)
     jax.eval_shape(lambda p, t: tfm.forward(p, cfg, t)[0], params, tokens)
     after = profile.traced()
-    return {k: after[k] - before[k] for k in ("wkv.pallas", "wkv.chunked")}
+    return {k: after[k] - before[k]
+            for k in ("wkv.pallas", "wkv.chunked", "wkv.pallas.packed")}
 
 
 def test_tracing_on_the_cpu_counts_every_block_on_the_chunk_scan():
     cfg = get_config("rwkv6-1.6b").reduced()
-    assert _traced_counts(cfg) == {"wkv.pallas": 0, "wkv.chunked": cfg.n_layers}
+    assert _traced_counts(cfg) == {"wkv.pallas": 0, "wkv.chunked": cfg.n_layers,
+                                   "wkv.pallas.packed": 0}
 
 
 def test_tracing_where_the_kernel_resolves_counts_every_block_on_it(monkeypatch):
-    cfg = get_config("rwkv6-1.6b").reduced()
+    cfg = get_config("rwkv6-1.6b").reduced()   # 4 heads of 64: two a lane row
     monkeypatch.setattr(tfm, "resolve_wkv", lambda: "pallas")
-    assert _traced_counts(cfg) == {"wkv.pallas": cfg.n_layers, "wkv.chunked": 0}
+    assert _traced_counts(cfg) == {"wkv.pallas": cfg.n_layers, "wkv.chunked": 0,
+                                   "wkv.pallas.packed": cfg.n_layers}
